@@ -30,7 +30,7 @@ EXPECTED_MODULES = (
     "test_prefix_cache", "test_quant_quality", "test_sampler",
     "test_scheduler_fuzz", "test_serving", "test_solver_properties",
     "test_spec", "test_system", "test_telemetry", "test_tp_serving",
-    "test_trace", "test_training",
+    "test_tpu_compile", "test_trace", "test_training",
 )
 
 

@@ -96,7 +96,7 @@ _JIT_NAMES = {"jax.jit"}
 _SHARD_MAP_NAMES = {
     "jax.experimental.shard_map.shard_map",
     "jax.experimental.shard_map",
-    "repro.distributed.compat.shard_map",
+    "jax.shard_map",
 }
 # functions whose first positional arg threads a KV pool / dense cache that
 # the serving stack re-binds from the jitted call's return: jitting them

@@ -61,7 +61,7 @@ def build_plan(cfg, *, sync_mode: str = "fast",
 
 
 def build_hetero_ctx(cfg, mode: str, *, sync_mode: str = "fast",
-                     interpret: bool = True, mixed_pairs=(),
+                     mixed_pairs=(),
                      verify_ks=(), extra_ms=(),
                      weight_quant: Optional[str] = None) -> HeteroCtx:
     """Profile + solve + wrap in the HeteroCtx that models thread through
@@ -69,7 +69,7 @@ def build_hetero_ctx(cfg, mode: str, *, sync_mode: str = "fast",
     _, plan = build_plan(cfg, sync_mode=sync_mode, mixed_pairs=mixed_pairs,
                          verify_ks=verify_ks, extra_ms=extra_ms,
                          weight_quant=weight_quant)
-    return HeteroCtx(mode=mode, plan=plan, interpret=interpret)
+    return HeteroCtx(mode=mode, plan=plan)
 
 
 def dispatch_prediction(plan, cfg, *, m=None, steps: int = 1,
@@ -136,7 +136,7 @@ class InferenceEngine:
                  table: Optional[LatencyTable] = None,
                  plan: Optional[PartitionPlan] = None,
                  buckets: tuple = STANDARD_BUCKETS,
-                 max_len: int = 2048, interpret: bool = True,
+                 max_len: int = 2048,
                  use_kernels: bool = True, rng=None, clock=None):
         # EngineStats timing reads the injected clock (serving/telemetry
         # Clock protocol) — MonotonicClock by default, FakeClock in tests
@@ -160,10 +160,10 @@ class InferenceEngine:
         else:
             self.table, self.plan = table or profile_analytic(cfg), plan
         # use_kernels: route MXU-path matmuls through the Pallas kernel
-        # (interpret mode on CPU — functional; CPU wall-times of the MXU
-        # path are NOT representative of silicon, the analytic arms are).
-        self.ctx = HeteroCtx(mode=mode, plan=self.plan,
-                             interpret=interpret) if use_kernels else None
+        # (compiled on a TPU, interpreted elsewhere — repro.kernels; CPU
+        # wall-times of the MXU path are NOT representative of silicon).
+        self.ctx = (HeteroCtx(mode=mode, plan=self.plan) if use_kernels
+                    else None)
         self.stats = EngineStats()
         self._prefill_cache: dict = {}
 

@@ -133,10 +133,8 @@ class HeteroCtx:
     """mode: 'xla' | 'mxu' | 'hetero-layer' | 'hetero-tensor'."""
     mode: str = "hetero-tensor"
     plan: Optional[PartitionPlan] = None
-    interpret: bool = True
     order_exchange: bool = True
     layer_mxu_threshold: int = 128       # hetero-layer: M >= this -> MXU path
-    stationary: str = "output"
     # VERIFY site class (speculative decoding): when set to (k, lanes), every
     # matmul consults plan.verify_decisions first — the solver's plan for the
     # M = lanes*(k+1) verification dispatch, not the generic nearest-M grid
@@ -164,11 +162,9 @@ class HeteroCtx:
         xp = _pad_to(_pad_to(x2, ALIGN, 0), ALIGN, 1)
         wp = _pad_to(_pad_to(w.astype(x2.dtype), ALIGN, 0), ALIGN, 1)
         if use_exchange:
-            y = mxu_matmul(wp.T, xp.T, interpret=self.interpret,
-                           stationary=self.stationary).T
+            y = mxu_matmul(wp.T, xp.T).T
         else:
-            y = mxu_matmul(xp, wp, interpret=self.interpret,
-                           stationary=self.stationary)
+            y = mxu_matmul(xp, wp)
         return y[:M, :N]
 
     def _mxu_quant(self, x2, w: QuantWeight):
@@ -182,11 +178,11 @@ class HeteroCtx:
         sp = _pad_to(w.scale, ALIGN, -1)
         if w.fmt == "int8":
             wqp = _pad_to(_pad_to(w.wq, ALIGN, 0), ALIGN, 1)
-            y = mxu_quant_matmul(xp, wqp, sp, interpret=self.interpret)
+            y = mxu_quant_matmul(xp, wqp, sp)
         else:
             # packed rows count ceil(K/2) pads to Kp//2 (Kp = xp's padded K)
             wqp = _pad_to(_pad_to(w.wq, ALIGN // 2, 0), ALIGN, 1)
-            y = mxu_q4_matmul(xp, wqp, sp, interpret=self.interpret)
+            y = mxu_q4_matmul(xp, wqp, sp)
         return y[:M, :N]
 
     def _xla(self, x2, w):

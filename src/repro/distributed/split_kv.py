@@ -22,9 +22,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -54,8 +54,7 @@ def combine_split_softmax(s, v_local, axis_name=None):
 
 
 def _mesh_axes():
-    from repro.distributed.compat import get_mesh
-    mesh = get_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     names = mesh.axis_names
     data = tuple(n for n in names if n != "model")
     return mesh, data

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -57,7 +59,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def decode_attention_pallas(q, k_cache, v_cache, length, *, block_k: int = 512,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """q [B,Hq,D]; caches [B,Smax,Hkv,D]; length: int32 scalar (valid prefix)."""
     B, Hq, D = q.shape
     _, S, Hkv, _ = k_cache.shape
@@ -92,6 +94,6 @@ def decode_attention_pallas(q, k_cache, v_cache, length, *, block_k: int = 512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, qp, kp, vp)
     return out.reshape(B, Hkv, G, D).reshape(B, Hq, D)

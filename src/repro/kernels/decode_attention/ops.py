@@ -11,7 +11,7 @@ from .kernel import decode_attention_pallas
 
 @partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, length, *, block_k: int = 512,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     D = q.shape[-1]
     Dp = -(-D // 128) * 128
     if Dp != D:

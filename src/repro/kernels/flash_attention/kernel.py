@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -71,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
-                           block_k: int = 256, interpret: bool = True):
+                           block_k: int = 256, interpret: bool | None = None):
     """q [B,Sq,Hq,D]; k,v [B,Sk,Hkv,D]; GQA handled by packing the G=Hq/Hkv
     query heads of one KV head into the q-block rows."""
     B, Sq, Hq, D = q.shape
@@ -107,7 +109,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
             pltpu.VMEM((block_q * G, 1), jnp.float32),
             pltpu.VMEM((block_q * G, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
     return out.reshape(B, Hkv, Sq, G, D).transpose(0, 2, 1, 3, 4) \
               .reshape(B, Sq, Hq, D)

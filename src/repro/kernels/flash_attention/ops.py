@@ -11,7 +11,7 @@ from .kernel import flash_attention_pallas
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True):
+                    block_k: int = 256, interpret: bool | None = None):
     """GQA flash attention; pads head_dim up to a 128 multiple (MXU lanes)."""
     D = q.shape[-1]
     Dp = -(-D // 128) * 128

@@ -1,17 +1,12 @@
 """MXU-path blocked matmul — the TPU analogue of the paper's NPU engine.
 
-Pallas TPU kernel with explicit BlockSpec VMEM tiling. Two grid orders expose
-the paper's order-sensitivity on real silicon:
-
-  * ``stationary="weight"``  — grid (n, k, m), m innermost: the (bk x bn)
-    weight tile stays resident in VMEM while activations stream through —
-    the systolic "weight stall" regime (paper Fig 2). Output blocks are
-    revisited per k-step, so partial sums round-trip HBM: cheap when M is
-    large (weight reuse dominates), expensive when M is small — exactly
-    NPU-2/NPU-3 (order/shape sensitivity).
-  * ``stationary="output"`` — grid (m, n, k), k innermost: the fp32
-    accumulator lives in a VMEM scratch and is written once; weight tiles
-    reload every k-step.
+Pallas TPU kernels with explicit BlockSpec VMEM tiling, all output-stationary:
+grid (m, n, k), k innermost, the fp32 accumulator lives in a VMEM scratch
+and each output block is written once. (A weight-stationary order, grid
+(n, k, m), revisits each output block once per k step with other blocks in
+between; Pallas on TPU writes a block back when its index changes and never
+reads it again, so its partial sums are lost on the chip. The paper's
+order sensitivity lives in the analytic cost model, core/characteristics.py.)
 
 Weight-only quantization (the paper's W4A16 stance): int8 weights + per-column
 fp32 scales are dequantized tile-by-tile in VMEM; activations stay bf16/f32.
@@ -24,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 128, 128
@@ -45,19 +42,6 @@ def _mm_kernel_output_stationary(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _mm_kernel_weight_stationary(x_ref, w_ref, o_ref, *, nk: int):
-    """grid (n, k, m); weight tile constant over innermost m sweep.
-    Output revisited per k -> read-modify-write accumulate in out dtype."""
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
-
-
 def _mm_kernel_quant(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int):
     """Output-stationary int8-weight matmul with in-VMEM dequant."""
     k = pl.program_id(2)
@@ -77,8 +61,8 @@ def _mm_kernel_quant(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int):
 
 def matmul_pallas(x: jax.Array, w: jax.Array, *,
                   bm: int = DEFAULT_BM, bk: int = DEFAULT_BK,
-                  bn: int = DEFAULT_BN, stationary: str = "output",
-                  out_dtype=None, interpret: bool = True) -> jax.Array:
+                  bn: int = DEFAULT_BN, out_dtype=None,
+                  interpret: bool | None = None) -> jax.Array:
     """x [M,K] @ w [K,N]. Dims must be multiples of the block sizes — this is
     the 'static graph' constraint of the MXU path (the NPU analogue); the
     HeteroInfer engine routes misaligned remainders to the XLA path instead."""
@@ -89,25 +73,9 @@ def matmul_pallas(x: jax.Array, w: jax.Array, *,
         f"misaligned ({M},{K},{N}) for blocks ({bm},{bk},{bn})"
     out_dtype = out_dtype or x.dtype
     nk = K // bk
-
-    if stationary == "weight":
-        grid = (N // bn, nk, M // bm)
-        return pl.pallas_call(
-            functools.partial(_mm_kernel_weight_stationary, nk=nk),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bk), lambda n, k, m: (m, k)),
-                pl.BlockSpec((bk, bn), lambda n, k, m: (k, n)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda n, k, m: (m, n)),
-            out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-            interpret=interpret,
-        )(x, w).astype(out_dtype)
-
-    grid = (M // bm, N // bn, nk)
     return pl.pallas_call(
         functools.partial(_mm_kernel_output_stationary, nk=nk),
-        grid=grid,
+        grid=(M // bm, N // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
@@ -115,26 +83,31 @@ def matmul_pallas(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
 
 
 def _mm_kernel_q4(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int):
     """Output-stationary W4A16 matmul: two int4 weights packed per int8
     byte along K (the paper's storage format); nibbles are unpacked and
-    dequantized in VMEM, activations stay high-precision."""
+    dequantized in VMEM, activations stay high-precision.
+
+    Unpacking runs in int32 (Mosaic has no int8 shifts): the sign-extended
+    byte shifted left 28 then arithmetically right 28 is the low nibble,
+    shifted right 4 the high one. The nibbles are stacked [lo; hi] along
+    K rather than interleaved, and ``q4_matmul_pallas`` permutes each
+    activation block's columns to the same [even; odd] order."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = w_ref[...]                                  # int8 [bk//2, bn]
-    lo = jnp.left_shift(packed, 4) >> 4                  # sign-extended low
-    hi = packed >> 4                                     # arithmetic high
-    bk2, bn = packed.shape
-    w = jnp.stack([lo, hi], axis=1).reshape(2 * bk2, bn) # interleaved K
-    w = w.astype(jnp.float32) * s_ref[...].astype(jnp.float32)
+    packed = w_ref[...].astype(jnp.int32)                # [bk//2, bn]
+    lo = jnp.right_shift(jnp.left_shift(packed, 28), 28)  # sign-extended low
+    hi = jnp.right_shift(packed, 4)                      # arithmetic high
+    w = jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
+    w = w * s_ref[...].astype(jnp.float32)
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -146,7 +119,7 @@ def _mm_kernel_q4(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int):
 def q4_matmul_pallas(x: jax.Array, wq4: jax.Array, scale: jax.Array, *,
                      bm: int = DEFAULT_BM, bk: int = DEFAULT_BK,
                      bn: int = DEFAULT_BN, out_dtype=None,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """x [M,K] @ dequant(wq4 int8-packed [K//2,N], scale f32 [N]).
     K-order inside wq4: row r holds original rows (2r, 2r+1) as (lo, hi)."""
     M, K = x.shape
@@ -155,6 +128,9 @@ def q4_matmul_pallas(x: jax.Array, wq4: jax.Array, scale: jax.Array, *,
     assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % 2 == 0
     out_dtype = out_dtype or x.dtype
     nk = K // bk
+    # within each K block, even columns first then odd ones: the order in
+    # which the kernel stacks the unpacked [lo; hi] weight rows
+    xp = x.reshape(M, nk, bk // 2, 2).swapaxes(2, 3).reshape(M, K)
     return pl.pallas_call(
         functools.partial(_mm_kernel_q4, nk=nk),
         grid=(M // bm, N // bn, nk),
@@ -166,14 +142,14 @@ def q4_matmul_pallas(x: jax.Array, wq4: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, wq4, scale[None, :])
+        interpret=resolve_interpret(interpret),
+    )(xp, wq4, scale[None, :])
 
 
 def quant_matmul_pallas(x: jax.Array, wq: jax.Array, scale: jax.Array, *,
                         bm: int = DEFAULT_BM, bk: int = DEFAULT_BK,
                         bn: int = DEFAULT_BN, out_dtype=None,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool | None = None) -> jax.Array:
     """x [M,K] @ dequant(wq int8 [K,N], scale f32 [N])."""
     M, K = x.shape
     _, N = wq.shape
@@ -191,5 +167,5 @@ def quant_matmul_pallas(x: jax.Array, wq: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, wq, scale[None, :])

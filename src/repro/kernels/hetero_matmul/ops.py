@@ -14,19 +14,20 @@ def _flatten_leading(x):
     return x.reshape(-1, x.shape[-1]), lead
 
 
-@partial(jax.jit, static_argnames=("bm", "bk", "bn", "stationary", "interpret"))
+@partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def mxu_matmul(x: jax.Array, w: jax.Array, *, bm=128, bk=128, bn=128,
-               stationary: str = "output", interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """[..., K] @ [K, N] on the aligned MXU path. Shapes must be aligned."""
     x2, lead = _flatten_leading(x)
-    y = matmul_pallas(x2, w, bm=bm, bk=bk, bn=bn, stationary=stationary,
-                      out_dtype=x.dtype, interpret=interpret)
+    y = matmul_pallas(x2, w, bm=bm, bk=bk, bn=bn, out_dtype=x.dtype,
+                      interpret=interpret)
     return y.reshape(*lead, w.shape[-1])
 
 
 @partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def mxu_quant_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array, *,
-                     bm=128, bk=128, bn=128, interpret: bool = True) -> jax.Array:
+                     bm=128, bk=128, bn=128,
+                     interpret: bool | None = None) -> jax.Array:
     x2, lead = _flatten_leading(x)
     y = quant_matmul_pallas(x2, wq, scale, bm=bm, bk=bk, bn=bn,
                             out_dtype=x.dtype, interpret=interpret)
@@ -75,7 +76,8 @@ def quantize_weight_int4(w: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 @partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def mxu_q4_matmul(x: jax.Array, wq4: jax.Array, scale: jax.Array, *,
-                  bm=128, bk=128, bn=128, interpret: bool = True) -> jax.Array:
+                  bm=128, bk=128, bn=128,
+                  interpret: bool | None = None) -> jax.Array:
     from .kernel import q4_matmul_pallas
     x2, lead = _flatten_leading(x)
     y = q4_matmul_pallas(x2, wq4, scale, bm=bm, bk=bk, bn=bn,

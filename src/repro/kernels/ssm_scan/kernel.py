@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _chunk_kernel(xb_ref, b_ref, c_ref, seg_ref, sprev_ref, y_ref, snew_ref,
                   *, L: int):
@@ -47,7 +49,8 @@ def _chunk_kernel(xb_ref, b_ref, c_ref, seg_ref, sprev_ref, y_ref, snew_ref,
     snew_ref[0, 0] = S_new.astype(snew_ref.dtype)
 
 
-def ssd_chunk_pallas(xb, B_, C_, seg, S_prev, *, interpret: bool = True):
+def ssd_chunk_pallas(xb, B_, C_, seg, S_prev, *,
+                     interpret: bool | None = None):
     """xb [B,L,nh,hd]; B_,C_ [B,L,N]; seg [B,L,nh]; S_prev [B,nh,hd,N].
     Returns (y [B,L,nh,hd], S_new [B,nh,hd,N])."""
     Bb, L, nh, hd = xb.shape
@@ -71,6 +74,6 @@ def ssd_chunk_pallas(xb, B_, C_, seg, S_prev, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bb, L, nh, hd), jnp.float32),
             jax.ShapeDtypeStruct((Bb, nh, hd, N), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xb, B_, C_, seg, S_prev)
     return y, S_new

@@ -10,7 +10,8 @@ from .kernel import ssd_chunk_pallas
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(xh, dt, A, B_, C_, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(xh, dt, A, B_, C_, *, chunk: int = 256,
+             interpret: bool | None = None):
     """Same contract as models.mamba2.ssd_chunked, Pallas chunk compute.
     xh [B,S,nh,hd]; dt [B,S,nh] (post-softplus); A [nh] (<0); B_,C_ [B,S,N].
     """

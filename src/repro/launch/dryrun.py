@@ -57,7 +57,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     import jax
     from repro.configs import get_config, SHAPES, cell_is_supported
     from repro.distributed.sharding import activation_sharding
-    from repro.distributed.compat import set_mesh
+    from jax.sharding import set_mesh
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import make_step_and_specs
     from repro.roofline.hlo_parse import collective_summary

@@ -1,4 +1,4 @@
-"""Serving launcher: ``python -m repro.launch.serve --arch llama3-8b --smoke
+"""Serving launcher: ``python -m repro.launch.serve --arch qwen3-1.7b --smoke
 --mode hetero-tensor --strategy hetero --requests 8``.
 
 Runs the HeteroInfer engine (single-stream, paper-faithful), the dense
@@ -43,6 +43,10 @@ Paged mode fuses the engine into the serving path
                     first; incompatible with --engine-mode)
   --stats           print the scheduler's unified stats() counter dict
 
+Weights are random, drawn from --seed, as are the prompts: lengths uniform
+in [--min-prompt-len, --prompt-len). ``main`` returns a ``ServeResult`` for
+callers that check what was served (chip_smoke.py).
+
 Batched serving always runs through the async ingress
 (serving/ingress.py): every request is timestamped against the wall clock
 (serving/telemetry.py) and the run reports TTFT / TPOT / queue-delay
@@ -62,13 +66,25 @@ arrive at t=0) as well as open loop:
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
 
 import numpy as np
 
 
+@dataclass
+class ServeResult:
+    """What one batched serving run produced: the batcher (with its params
+    and pool), the request handles in submission order, and the prompts."""
+    batcher: object
+    handles: list
+    prompts: list
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--mode", default="hetero-tensor",
                     choices=["xla", "mxu", "hetero-layer", "hetero-tensor"])
@@ -173,6 +189,10 @@ def main(argv=None):
                          "(paged mode)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=300)
+    ap.add_argument("--min-prompt-len", type=int, default=8,
+                    dest="min_prompt_len",
+                    help="shortest prompt (batched mode; before any "
+                         "--shared-prefix)")
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args(argv)
     if (args.sync == "device" or args.engine_mode or args.eos_id is not None
@@ -207,10 +227,15 @@ def main(argv=None):
         ap.error("--trace-out / --metrics-out / --plan-drift trace the "
                  "batched servers: add --batched")
 
+    if not 8 <= args.min_prompt_len < args.prompt_len:
+        ap.error("--min-prompt-len must be in [8, --prompt-len)")
+
     import jax
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
 
     if args.batched:
         from repro.serving.scheduler import ContinuousBatcher, PagedBatcher
@@ -250,7 +275,7 @@ def main(argv=None):
                               spec=spec, prefix_cache=args.prefix_cache,
                               weight_quant=args.weight_quant,
                               kv_quant=args.kv_quant, mesh=mesh,
-                              tracer=tracer)
+                              seed=args.seed, tracer=tracer)
             label = (f"paged (bs={args.block_size}, "
                      f"blocks={num_blocks}, W={args.decode_width}, "
                      f"sync={args.sync}"
@@ -269,17 +294,18 @@ def main(argv=None):
                         if spec else "") + ")")
         else:
             cb = ContinuousBatcher(cfg, max_batch=4, max_len=max_len,
-                                   tracer=tracer)
+                                   seed=args.seed, tracer=tracer)
             label = "batched"
         if args.shared_prefix >= args.prompt_len - 8:
             ap.error("--shared-prefix must leave at least 8 tokens of "
                      "per-request tail below --prompt-len")
         sys_prompt = rng.integers(0, cfg.vocab_size,
                                   args.shared_prefix).astype(np.int32)
+        lo = max(8, args.min_prompt_len - args.shared_prefix)
         prompts = [np.concatenate([
             sys_prompt,
             rng.integers(0, cfg.vocab_size,
-                         rng.integers(8, args.prompt_len
+                         rng.integers(lo, args.prompt_len
                                       - args.shared_prefix)
                          ).astype(np.int32)])
             for _ in range(args.requests)]
@@ -351,12 +377,13 @@ def main(argv=None):
                 print(f"  metrics: -> {args.metrics_out}")
             if args.plan_drift:
                 print(tracer.drift.format_table())
-        return
+        return ServeResult(batcher=cb, handles=handles, prompts=prompts)
 
     from repro.core.engine import InferenceEngine
     eng = InferenceEngine(cfg, mode=args.mode, prefill_strategy=args.strategy,
                           fast_sync=not args.no_fast_sync,
-                          max_len=args.prompt_len + args.new_tokens + 8)
+                          max_len=args.prompt_len + args.new_tokens + 8,
+                          rng=jax.random.PRNGKey(args.seed))
     prompt = rng.integers(0, cfg.vocab_size,
                           (1, args.prompt_len)).astype(np.int32)
     toks = eng.generate(jax.numpy.asarray(prompt), args.new_tokens)

@@ -29,8 +29,11 @@ Every sharded matrix splits on its OUTPUT axis, never the contraction axis:
 each shard computes full-depth reductions for its slice of the output
 columns and a tiled ``all_gather`` concatenates the slices in shard order.
 That makes TP an execution schedule, never a numerics change — greedy token
-streams are bit-identical to the single-device batcher (a row-parallel
-psum-of-partials would reassociate the reduction and drift at ULP level).
+streams are bit-identical to the single-device batcher in the f32 CPU
+tests (a row-parallel psum-of-partials would reassociate the reduction and
+drift at ULP level). On a TPU v5e in bf16 the compiler lowers the narrower
+shard matmuls differently, so TP=4 and TP=1 logits differ by bf16 rounding
+and long greedy streams can part at near-ties.
 Quantized sites shard the same way: ``QuantWeight`` codes and their
 per-output-channel scales both split along N, so w4a16's K-axis nibble
 packing is never cut. Per-step collectives (decode, B lanes, d = d_model):
@@ -50,10 +53,10 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.partition import QuantWeight
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import sanitize_spec
 from repro.models import transformer
 

@@ -111,7 +111,6 @@ class ContinuousBatcher:
                  max_len: int = 512, buckets=(64, 128, 256),
                  sampler: SamplerConfig = SamplerConfig(), seed: int = 0,
                  weight_quant: str | None = None, tracer=None):
-        assert cfg.moe is None or True
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.cfg = cfg
         self.model = build_model(cfg)
@@ -126,7 +125,10 @@ class ContinuousBatcher:
         self.sampler = sampler
         self.rng = jax.random.PRNGKey(seed)
 
-        self.cache = self.model.init_cache(batch=max_batch, max_len=max_len)
+        # the cache holds activations: compute dtype, as the paged pool does
+        self.cache = self.model.init_cache(
+            batch=max_batch, max_len=max_len,
+            dtype=jnp.dtype(cfg.compute_dtype))
         self.cache["index"] = jnp.zeros((max_batch,), jnp.int32)
         self.slots: list[Optional[Request]] = [None] * max_batch
         self.queue: list[Request] = []
@@ -349,7 +351,7 @@ class PagedBatcher:
                  mixed_batch: bool = False,
                  max_prefill_chunk_per_step: int | None = None,
                  spec: SpecConfig | int | None = None,
-                 spec_draft_params=None, interpret: bool = True,
+                 spec_draft_params=None,
                  prefix_cache: bool = False,
                  weight_quant: str | None = None,
                  kv_quant: str | None = None,
@@ -469,8 +471,7 @@ class PagedBatcher:
                           if prefix_cache else ()),
                 # quantized weight-stream bytes change the roofline: the
                 # solver re-plans memory-bound (decode-width) shapes
-                weight_quant=weight_quant,
-                interpret=interpret)
+                weight_quant=weight_quant)
         else:
             self.ctx = None
         # the solved plan (None without an engine mode) backs the tracer's
@@ -672,21 +673,7 @@ class PagedBatcher:
             if seq is None:
                 break                    # FCFS: wait for blocks to free
             req = self.queue.pop(0)
-            bt = jnp.asarray(seq.table)[None]
-            idx, logits = seq.cached_tokens, None
-            for c in bucket_chunks(len(req.prompt) - seq.cached_tokens,
-                                   self.buckets):
-                piece = jnp.asarray(req.prompt[idx: idx + c], jnp.int32)
-                with self._dispatch_span("prefill_chunk", "prefill",
-                                         ({"m": c},), rid=req.rid,
-                                         chunk=c, start=idx):
-                    logits, self.kv.pool = self._prefill(
-                        self.params, piece[None], self.kv.pool,
-                        block_table=bt,
-                        start_index=jnp.asarray(idx, jnp.int32))
-                self.prefill_dispatches += 1
-                self.tracer.count("prefill_dispatches")
-                idx += c
+            logits = self._prefill_prompt(req.prompt, seq, req.rid)
             self.rng, k = jax.random.split(self.rng)
             lane = self._place(req, seq, int(sample(logits[:, -1, :], k,
                                                     self.sampler)[0]))
@@ -695,6 +682,39 @@ class PagedBatcher:
                 # the draft model consumes the prompt too (its lane cache
                 # must mirror the target's token stream before drafting)
                 self.drafts.prefill(lane, req.prompt)
+
+    def _prefill_prompt(self, prompt, seq: SequenceBlocks, rid: int):
+        """Prefill ``prompt`` into ``seq``'s blocks as bucket-sized chunk
+        dispatches, starting after the ``seq.cached_tokens`` positions
+        already resident; returns the last chunk's logits [1, 1, V]."""
+        bt = jnp.asarray(seq.table)[None]
+        idx, logits = seq.cached_tokens, None
+        for c in bucket_chunks(len(prompt) - seq.cached_tokens,
+                               self.buckets):
+            piece = jnp.asarray(prompt[idx: idx + c], jnp.int32)
+            with self._dispatch_span("prefill_chunk", "prefill",
+                                     ({"m": c},), rid=rid,
+                                     chunk=c, start=idx):
+                logits, self.kv.pool = self._prefill(
+                    self.params, piece[None], self.kv.pool,
+                    block_table=bt,
+                    start_index=jnp.asarray(idx, jnp.int32))
+            self.prefill_dispatches += 1
+            self.tracer.count("prefill_dispatches")
+            idx += c
+        return logits
+
+    def prompt_logits(self, prompt) -> jax.Array:
+        """Last-position logits [V] of ``prompt`` through this batcher's
+        own admission prefill (chunking, plan, layout and pool), with no
+        request admitted: the logits a request's first token is sampled
+        from. The blocks it borrows are returned before it does."""
+        seq = self.kv.open_sequence(prompt_tokens=len(prompt),
+                                    total_tokens=len(prompt))
+        try:
+            return self._prefill_prompt(prompt, seq, -1)[0, -1]
+        finally:
+            self.kv.close_sequence(seq)
 
     def _start_admission(self):
         """Mixed batching: take ONE admission ticket at a time. A free lane

@@ -217,7 +217,7 @@ class SpecDecoder:
                  block_size: int = 32, max_len: int = 512,
                  buckets=(64, 128, 256), engine_mode: Optional[str] = None,
                  sync: str = "host", eos_id: Optional[int] = None,
-                 cache_dtype=None, seed: int = 0, interpret: bool = True):
+                 cache_dtype=None, seed: int = 0):
         if sync not in ("host", "device"):
             raise ValueError(f"sync must be 'host' or 'device', got {sync!r}")
         self.cfg = cfg
@@ -255,7 +255,7 @@ class SpecDecoder:
             self.ctx = build_hetero_ctx(
                 cfg, engine_mode,
                 sync_mode="fast" if sync == "device" else "host",
-                verify_ks=((spec.k, 1),), interpret=interpret)
+                verify_ks=((spec.k, 1),))
             vctx = self.ctx.for_verify(spec.k, 1)
         else:
             self.ctx = vctx = None

@@ -13,7 +13,8 @@ from repro.distributed.compression import (compress_grads_with_feedback,
                                            compressed_psum, init_error)
 from repro.distributed.sharding import (batch_sharding, cache_specs,
                                         param_specs, sanitize_spec)
-from repro.distributed.compat import set_mesh, shard_map
+from jax import shard_map
+from jax.sharding import set_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_step_and_specs
 from repro.models import build_model

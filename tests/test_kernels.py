@@ -1,4 +1,4 @@
-"""Kernel-specific PROPERTY tests (grid orders, GQA ratios, block sizes,
+"""Kernel-specific PROPERTY tests (tile counts, GQA ratios, block sizes,
 model-path equivalence). Plain dtype/shape parity — including ragged-M and
 odd-K edge cases — lives in the unified conformance harness
 (test_kernel_conformance.py, one shared parameterization for all four
@@ -19,16 +19,14 @@ from repro.kernels.hetero_matmul.ref import matmul_ref
 RNG = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("tm,tk,tn,stationary", [
-    (1, 1, 1, "output"), (2, 3, 1, "weight"), (3, 1, 2, "output"),
-    (1, 2, 3, "weight"), (2, 2, 2, "output"), (3, 3, 3, "weight")])
-def test_mxu_matmul_property(tm, tk, tn, stationary):
-    """Any tile-aligned shape agrees with the oracle (both grid orders)."""
+@pytest.mark.parametrize("tm,tk,tn", [(1, 1, 1), (3, 1, 2), (2, 2, 2)])
+def test_mxu_matmul_property(tm, tk, tn):
+    """Any tile-aligned shape agrees with the oracle."""
     M, K, N = tm * 128, tk * 128, tn * 128
     k1, k2 = jax.random.split(RNG)
     x = jax.random.normal(k1, (M, K), jnp.float32)
     w = jax.random.normal(k2, (K, N), jnp.float32)
-    assert rel_err(mxu_matmul(x, w, stationary=stationary),
+    assert rel_err(mxu_matmul(x, w),
                    matmul_ref(x, w)) < 2e-6
 
 
