@@ -1,0 +1,7 @@
+"""ttft_p70_ms: 70th percentile over the window's requests of first token
+minus scheduled arrival."""
+from bench.readers import percentile, ttft_ms
+
+
+def read(run):
+    return percentile(ttft_ms(run), 70)
