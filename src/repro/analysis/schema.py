@@ -8,8 +8,8 @@ Four artifacts describe the same schema and drift independently:
      sites scattered through serving/,
   3. ``STATS_COUNTER_KEYS`` / ``STATS_GAUGE_KEYS`` in serving/trace.py
      (what ``counter_reconciliation()`` reconciles), and
-  4. the counter/gauge bullets and dispatch-span table in
-     docs/observability.md.
+  4. the counter/gauge bullets and the dispatch-, sync- and host-span
+     tables in docs/observability.md.
 
 The runtime contract (``counter_reconciliation``) only catches a drift when
 a test exercises the drifted counter; this rule proves all four artifacts
@@ -116,14 +116,17 @@ def _module_tuple(tree: ast.AST, name: str) -> tuple | None:
 def _tracer_emissions(modules: list[Module]):
     """Literal tracer call sites across the tree.
 
-    Returns (counts, gauges, kinds): each a dict name -> first (mod, line).
-    Receivers must END in ``tracer`` (``self.tracer``, a bare ``tracer``),
-    which deliberately excludes trace.py's internal ``self.metrics.count``.
-    Dispatch kinds come from ``.dispatch("lit")``, ``._dispatch_span("lit")``
-    and ``.span("lit", ..., cat="sync")`` (core/sync.py's fused_window)."""
+    Returns (counts, gauges, kinds, syncs, hosts): each a dict name ->
+    first (mod, line). Receivers must END in ``tracer`` (``self.tracer``, a
+    bare ``tracer``), which deliberately excludes trace.py's internal
+    ``self.metrics.count``. Dispatch kinds come from ``.dispatch("lit")``
+    and ``._dispatch_span("lit")``; sync spans from ``.span("lit", ...,
+    cat="sync")``; host spans from every other ``.span("lit")``."""
     counts: dict = {}
     gauges: dict = {}
     kinds: dict = {}
+    syncs: dict = {}
+    hosts: dict = {}
 
     def first_str(call: ast.Call):
         if call.args and isinstance(call.args[0], ast.Constant) \
@@ -149,12 +152,12 @@ def _tracer_emissions(modules: list[Module]):
                     recv_is_tracer or meth == "_dispatch_span"):
                 kinds.setdefault(lit, (mod, node.lineno))
             elif meth == "span" and recv_is_tracer:
-                for kw in node.keywords:
-                    if kw.arg == "cat" \
-                            and isinstance(kw.value, ast.Constant) \
-                            and kw.value.value == "sync":
-                        kinds.setdefault(lit, (mod, node.lineno))
-    return counts, gauges, kinds
+                sync = any(kw.arg == "cat"
+                           and isinstance(kw.value, ast.Constant)
+                           and kw.value.value == "sync"
+                           for kw in node.keywords)
+                (syncs if sync else hosts).setdefault(lit, (mod, node.lineno))
+    return counts, gauges, kinds, syncs, hosts
 
 
 # ----------------------------------------------------------- docs extraction
@@ -188,11 +191,12 @@ def _doc_sections(text: str) -> dict:
     return out
 
 
-def _doc_dispatch_names(text: str) -> tuple[set, int]:
-    """First-column backtick names from the dispatch-span table (combined
-    cells like ``mixed_step`` / ``mixed_window`` split into both)."""
-    m = re.search(r"\*\*Dispatch spans\*\*(.*?)(?=\n\*\*|\n## |\Z)",
-                  text, re.S)
+def _doc_table_names(text: str, title: str) -> tuple[set, int]:
+    """First-column backtick names from the table under the bold ``title``
+    (combined cells like ``mixed_step`` / ``mixed_window`` split into
+    both)."""
+    m = re.search(r"\*\*" + re.escape(title)
+                  + r"\*\*(.*?)(?=\n\*\*|\n## |\Z)", text, re.S)
     if m is None:
         return set(), 0
     line0 = text[:m.start()].count("\n") + 1
@@ -268,7 +272,7 @@ def check_schema_contract(root: Path, modules: list[Module],
                      f"merge into one stats() snapshot")
 
     # --- 2. tracer emission sites -----------------------------------------
-    counts, gauges, kinds = _tracer_emissions(modules)
+    counts, gauges, kinds, syncs, hosts = _tracer_emissions(modules)
     legal_counts = counter_keys | set(config.extra_counters)
     for name, (mod, line) in sorted(counts.items()):
         if name not in legal_counts:
@@ -313,14 +317,16 @@ def check_schema_contract(root: Path, modules: list[Module],
         fail(config.docs_relpath, 1,
              f"docs list gauge {key!r} which the code does not emit")
 
-    doc_kinds, table_line = _doc_dispatch_names(text)
-    code_kinds = set(kinds)
-    for k in sorted(code_kinds - doc_kinds):
-        mod, line = kinds[k]
-        fail(mod.relpath, line,
-             f"dispatch span kind {k!r} is emitted but missing from the "
-             f"docs dispatch-span table", mod.line_at(line))
-    for k in sorted(doc_kinds - code_kinds):
-        fail(config.docs_relpath, table_line,
-             f"docs dispatch-span table names {k!r} but no code emits it")
+    for title, emitted in (("Dispatch spans", kinds), ("Sync spans", syncs),
+                           ("Host spans", hosts)):
+        what = title[:-1].lower()               # "dispatch span", ...
+        doc_names, table_line = _doc_table_names(text, title)
+        for k in sorted(set(emitted) - doc_names):
+            mod, line = emitted[k]
+            fail(mod.relpath, line,
+                 f"{what} {k!r} is emitted but missing from the docs "
+                 f"{what} table", mod.line_at(line))
+        for k in sorted(doc_names - set(emitted)):
+            fail(config.docs_relpath, table_line,
+                 f"docs {what} table names {k!r} but no code emits it")
     return findings

@@ -30,7 +30,6 @@ backend — the number the solver uses as T_sync in 'host' mode.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 from functools import lru_cache, partial
 
 import jax
@@ -172,8 +171,7 @@ def paged_decode_window(model, params, last_token, pool, block_tables,
                         lengths, remaining, rng, n_steps: int, *,
                         sampler=None, eos_id=None, prefill_tokens=None,
                         prefill_table=None, prefill_start=0,
-                        mixed_step_fn=None, decode_step_fn=None,
-                        tracer=None):
+                        mixed_step_fn=None, decode_step_fn=None):
     """Fused-window paged decode: ONE dispatch for ``n_steps`` batched steps.
 
     last_token: [W, 1] each lane's most recent token; block_tables: [W, NBmax]
@@ -198,34 +196,23 @@ def paged_decode_window(model, params, last_token, pool, block_tables,
     windows; they default to the model's own step functions. The override is
     how tensor-parallel serving threads its sharded step into the fused
     window: the shard_map body simply becomes the scanned step.
-
-    ``tracer`` (duck-typed — core never imports serving) wraps the fused
-    dispatch in a ``fused_window`` span so the trace shows the window
-    boundary — the one host round-trip — nested inside the scheduler's
-    dispatch span. The span surrounds the HOST-side jit call only; nothing
-    traced runs inside the compiled graph.
     """
     keys = jax.random.split(rng, n_steps)
     decode_step = (decode_step_fn if decode_step_fn is not None
                    else model.paged_decode_step)
-    mixed = prefill_tokens is not None
-    span = (nullcontext() if tracer is None else
-            tracer.span("fused_window", track="decode", cat="sync",
-                        args={"n_steps": int(n_steps), "mixed": mixed}))
-    with span:
-        if not mixed:
-            return _paged_window(params, last_token, pool, block_tables,
-                                 lengths, remaining, keys,
-                                 decode_step=decode_step, n_steps=n_steps,
-                                 sampler=sampler, eos_id=eos_id)
-        return _paged_mixed_window(
-            params, last_token, pool, block_tables, lengths, remaining, keys,
-            prefill_tokens, prefill_table,
-            jnp.asarray(prefill_start, jnp.int32),
-            decode_step=decode_step,
-            mixed_step=(mixed_step_fn if mixed_step_fn is not None
-                        else model.mixed_step),
-            n_steps=n_steps, sampler=sampler, eos_id=eos_id)
+    if prefill_tokens is None:
+        return _paged_window(params, last_token, pool, block_tables,
+                             lengths, remaining, keys,
+                             decode_step=decode_step, n_steps=n_steps,
+                             sampler=sampler, eos_id=eos_id)
+    return _paged_mixed_window(
+        params, last_token, pool, block_tables, lengths, remaining, keys,
+        prefill_tokens, prefill_table,
+        jnp.asarray(prefill_start, jnp.int32),
+        decode_step=decode_step,
+        mixed_step=(mixed_step_fn if mixed_step_fn is not None
+                    else model.mixed_step),
+        n_steps=n_steps, sampler=sampler, eos_id=eos_id)
 
 
 @lru_cache(maxsize=16)

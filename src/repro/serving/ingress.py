@@ -386,13 +386,15 @@ class AsyncServer:
         self.ticks += 1
         self.tracer.count("ingress_ticks")
         with self.tracer.span("tick", track="ingress"):
-            admitted = self._admit_phase()
+            with self.tracer.span("admit", track="ingress"):
+                admitted = self._admit_phase()
             progressed = False
             if self.batcher.busy:
                 progressed = bool(self.batcher.step())
                 if self.step_time_s is not None and (progressed or admitted):
                     self.clock.advance(self.step_time_s)
-            self._drain_phase()
+            with self.tracer.span("drain", track="ingress"):
+                self._drain_phase()
         return bool(admitted) or progressed
 
     @property
@@ -439,7 +441,8 @@ class AsyncServer:
                             "priority)")
                 await asyncio.sleep(0)   # let stream consumers run
             elif pending:
-                await self.clock.sleep(pending[0][0] - now)
+                with self.tracer.span("await_arrival", track="ingress"):
+                    await self.clock.sleep(pending[0][0] - now)
             else:
                 break
         return self.handles

@@ -66,7 +66,19 @@ from repro.models import build_model
 from .paged_cache import PagedKVCache, SequenceBlocks
 from .sampler import SamplerConfig, greedy_verify, sample
 from .spec import DraftLanes, SpecConfig
+from .telemetry import MonotonicClock
 from .trace import NULL_TRACER
+
+
+def _named(name: str, fn, **bound):
+    """``partial(fn, **bound)`` as a plain function called ``name``: jit
+    names its program after it (``jit_<name>``), where a partial of a
+    partial lowers as ``jit__unknown``. Made once per batcher, so its
+    identity is stable for jit's caches and as a static argument."""
+    def call(*args, **kw):
+        return fn(*args, **bound, **kw)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def bucket_chunks(S: int, buckets: tuple) -> list[int]:
@@ -90,6 +102,13 @@ class Request:
     max_new_tokens: int = 16
     output: list = field(default_factory=list)
     done: bool = False
+    # TTFT stages, stamped by PagedBatcher on its clock: submitted, first
+    # prefill chunk dispatched, first token on the host, and the end of the
+    # step that placed it (when ingress can stream it)
+    t_submit: Optional[float] = None
+    t_prefill: Optional[float] = None
+    t_first: Optional[float] = None
+    t_placed: Optional[float] = None
 
 
 def _validate_submit(req: Request, live_rids) -> None:
@@ -201,15 +220,16 @@ class ContinuousBatcher:
         last = np.zeros((self.B, 1), np.int32)
         for b in active:
             last[b, 0] = self.slots[b].output[-1]
+        self.rng, k = jax.random.split(self.rng)
         # decode_step itself advances every slot's index by one
         with self.tracer.dispatch("decode_step", track="decode",
                                   args={"active": len(active)}):
             logits, self.cache = self._decode(self.params,
                                               jnp.asarray(last), self.cache)
+            with self.tracer.span("step_sync", track="decode", cat="sync"):
+                toks = np.asarray(sample(logits[:, -1, :], k, self.sampler))
         self.decode_dispatches += 1
         self.tracer.count("decode_dispatches")
-        self.rng, k = jax.random.split(self.rng)
-        toks = np.asarray(sample(logits[:, -1, :], k, self.sampler))
         for b in active:
             req = self.slots[b]
             req.output.append(int(toks[b]))
@@ -415,6 +435,9 @@ class PagedBatcher:
         # observability: NULL_TRACER (shared no-op) unless the caller wires
         # a live Tracer — the pool and draft lanes record into the same one
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # the TTFT stage counters' clock: the tracer's, when one is wired
+        self.clock = (self.tracer.clock if self.tracer.enabled
+                      else MonotonicClock())
         self.kv = PagedKVCache(
             cfg, num_blocks=num_blocks, block_size=block_size,
             max_blocks_per_seq=max_blocks_per_seq,
@@ -486,6 +509,15 @@ class PagedBatcher:
         self.prefill_dispatches = 0      # standalone prefill-chunk dispatches
         self.fused_steps = 0             # prefill chunks fused into decode
         self.preemptions = 0             # lanes evicted mid-flight (ingress)
+        # TTFT stages, seconds summed over the first tokens placed: submit
+        # -> first prefill chunk dispatched (earlier prefills, the FCFS wait
+        # for blocks) -> first token on the host -> end of the step that
+        # placed it (the rest of that step: the decode window)
+        self.first_tokens = 0
+        self.prefill_wait_s = 0.0
+        self.prefill_host_s = 0.0
+        self.first_token_hold_s = 0.0
+        self._placed: list[Request] = []  # first tokens placed this step
         # speculative decoding counters (spec mode): the win is
         # verify_dispatches << decode_steps; acceptance_rate explains it
         self.spec_rounds = 0             # per-lane speculation rounds
@@ -518,8 +550,9 @@ class PagedBatcher:
                 tracer=self.tracer)
             vctx = (self.ctx.for_verify(spec.k, decode_width)
                     if self.ctx is not None else None)
-            self._verify = jax.jit(partial(paged_fns["paged_verify"],
-                                           hetero_ctx=vctx),
+            self._verify = jax.jit(_named("paged_verify",
+                                          paged_fns["paged_verify"],
+                                          hetero_ctx=vctx),
                                    donate_argnums=(2,))
             self._accept = jax.jit(greedy_verify)
         else:
@@ -535,8 +568,9 @@ class PagedBatcher:
         # advance'): jit compiles one graph per chunk length, so standard
         # buckets hit the compile cache and only a novel ragged remainder
         # pays the trace+compile that bucketing amortizes
-        self._prefill = jax.jit(partial(paged_fns["paged_prefill"],
-                                        hetero_ctx=self.ctx),
+        self._prefill = jax.jit(_named("paged_prefill",
+                                       paged_fns["paged_prefill"],
+                                       hetero_ctx=self.ctx),
                                 donate_argnums=(2,))
         self._decode = jax.jit(paged_fns["paged_decode_step"],
                                donate_argnums=(2,))
@@ -547,8 +581,8 @@ class PagedBatcher:
                                 if mesh is not None else None)
         # stable callables (one jit cache each) for the mixed-batch arms:
         # decode lanes stay on the flexible path, the chunk gets the ctx
-        self._mixed_step_fn = partial(paged_fns["mixed_step"],
-                                      hetero_ctx=self.ctx)
+        self._mixed_step_fn = _named("mixed_step", paged_fns["mixed_step"],
+                                     hetero_ctx=self.ctx)
         self._mixed = jax.jit(self._mixed_step_fn, donate_argnums=(3,))
 
     def _dispatch_span(self, kind: str, track: str, specs=(), **args):
@@ -592,6 +626,10 @@ class PagedBatcher:
             "decode_steps": self.decode_steps,
             "prefill_dispatches": self.prefill_dispatches,
             "fused_steps": self.fused_steps,
+            "first_tokens": self.first_tokens,
+            "prefill_wait_s": self.prefill_wait_s,
+            "prefill_host_s": self.prefill_host_s,
+            "first_token_hold_s": self.first_token_hold_s,
             "preemptions": self.preemptions,
             "total_dispatches": self.total_dispatches,
         }
@@ -626,6 +664,7 @@ class PagedBatcher:
         if self._admitting is not None:
             live.add(self._admitting.req.rid)
         _validate_submit(req, live)
+        req.t_submit = self.clock.now()
         self.queue.append(req)
 
     def _try_open(self, req: Request) -> Optional[SequenceBlocks]:
@@ -648,9 +687,28 @@ class PagedBatcher:
             prompt_tokens=S, total_tokens=total,
             token_ids=req.prompt if self.prefix_cache else None)
 
+    def _first_token(self, logits) -> int:
+        """Sample a request's first token from its prefill's last logits:
+        the host waits here for the prefill."""
+        self.rng, k = jax.random.split(self.rng)
+        with self.tracer.span("first_token_sync", track="prefill",
+                              cat="sync"):
+            return int(sample(logits[:, -1, :], k, self.sampler)[0])
+
     def _place(self, req: Request, seq: SequenceBlocks, first: int) -> int:
         """Prefill done: record the prefill-sampled token and occupy a lane
-        (returned so spec mode can target the lane's draft cache)."""
+        (returned so spec mode can target the lane's draft cache). Closes
+        the request's prefill stages; its hold closes at the step's end."""
+        req.t_first = self.clock.now()
+        wait = req.t_prefill - req.t_submit
+        host = req.t_first - req.t_prefill
+        self.first_tokens += 1
+        self.prefill_wait_s += wait
+        self.prefill_host_s += host
+        self.tracer.count("first_tokens")
+        self.tracer.count("prefill_wait_s", wait)
+        self.tracer.count("prefill_host_s", host)
+        self._placed.append(req)
         seq.length = len(req.prompt)
         req.output.append(first)
         budget = req.max_new_tokens - 1
@@ -673,10 +731,11 @@ class PagedBatcher:
             if seq is None:
                 break                    # FCFS: wait for blocks to free
             req = self.queue.pop(0)
-            logits = self._prefill_prompt(req.prompt, seq, req.rid)
-            self.rng, k = jax.random.split(self.rng)
-            lane = self._place(req, seq, int(sample(logits[:, -1, :], k,
-                                                    self.sampler)[0]))
+            with self.tracer.span("prefill_request", track="prefill",
+                                  args={"rid": req.rid}):
+                req.t_prefill = self.clock.now()
+                logits = self._prefill_prompt(req.prompt, seq, req.rid)
+                lane = self._place(req, seq, self._first_token(logits))
             if self.spec is not None and self.lanes[lane] is not None \
                     and self.lanes[lane].budget > 0:
                 # the draft model consumes the prompt too (its lane cache
@@ -737,6 +796,8 @@ class PagedBatcher:
         """Pop the admitting request's next chunk as device operands:
         (tokens [1, C], block table [1, NBmax], start index)."""
         adm = self._admitting
+        if adm.req.t_prefill is None:
+            adm.req.t_prefill = self.clock.now()
         c = adm.chunks.pop(0)
         piece = jnp.asarray(adm.req.prompt[adm.idx: adm.idx + c],
                             jnp.int32)[None]
@@ -746,11 +807,12 @@ class PagedBatcher:
 
     def _finish_admission(self, pre_logits):
         """Last chunk landed: sample the prefill token and occupy the lane
-        reserved at `_start_admission`."""
+        reserved at `_start_admission`. The prompt's chunks rode earlier
+        dispatches, so ``prefill_request`` here holds the sync alone."""
         adm, self._admitting = self._admitting, None
-        self.rng, k = jax.random.split(self.rng)
-        self._place(adm.req, adm.seq,
-                    int(sample(pre_logits[:, -1, :], k, self.sampler)[0]))
+        with self.tracer.span("prefill_request", track="prefill",
+                              args={"rid": adm.req.rid}):
+            self._place(adm.req, adm.seq, self._first_token(pre_logits))
 
     def _close_lane(self, lane: int) -> _PagedLane:
         """Return lane ``lane``'s pool references (shared by finish and
@@ -804,7 +866,20 @@ class PagedBatcher:
         ``self.window`` steps in one dispatch (sync='device'). Under mixed
         batching the admitting request's next prompt chunk rides the same
         dispatch; a standalone prefill dispatch happens only when no lane
-        is decoding."""
+        is decoding. First tokens placed in the step are held until it
+        returns, when ingress can stream them: that hold is counted here."""
+        progressed = self._step()
+        if self._placed:
+            now = self.clock.now()
+            for req in self._placed:
+                req.t_placed = now
+                hold = now - req.t_first
+                self.first_token_hold_s += hold
+                self.tracer.count("first_token_hold_s", hold)
+            self._placed.clear()
+        return progressed
+
+    def _step(self):
         if self.mixed_batch:
             self._start_admission()
         else:
@@ -871,19 +946,23 @@ class PagedBatcher:
         cursors. Emits 1..K+1 verified tokens per lane per target dispatch;
         the stream is bit-identical to the non-spec greedy arms."""
         k = self.spec.k
-        tables = np.zeros((self.W, self.kv.max_blocks_per_seq), np.int32)
-        starts = np.zeros((self.W,), np.int32)
-        last = np.zeros((self.W, 1), np.int32)
-        for i in active:
-            st = self.lanes[i]
-            # coverage capped by the remaining budget: only rows the
-            # acceptance rule can emit are ever read, so growth stays
-            # inside the admission reservation; writes past the covered
-            # blocks sink into the null block like any masked lane
-            self.kv.grow_to(st.seq, st.seq.length + min(k + 1, st.budget))
-            tables[i] = st.seq.table
-            starts[i] = st.seq.length
-            last[i, 0] = st.req.output[-1]
+        with self.tracer.span("verify_prep", track="verify"):
+            tables = np.zeros((self.W, self.kv.max_blocks_per_seq),
+                              np.int32)
+            starts = np.zeros((self.W,), np.int32)
+            last = np.zeros((self.W, 1), np.int32)
+            for i in active:
+                st = self.lanes[i]
+                # coverage capped by the remaining budget: only rows the
+                # acceptance rule can emit are ever read, so growth stays
+                # inside the admission reservation; writes past the covered
+                # blocks sink into the null block like any masked lane
+                self.kv.grow_to(st.seq,
+                                st.seq.length + min(k + 1, st.budget))
+                tables[i] = st.seq.table
+                starts[i] = st.seq.length
+                last[i, 0] = st.req.output[-1]
+            tables, starts = jnp.asarray(tables), jnp.asarray(starts)
         drafts = self.drafts.draft(last, k)                    # [W, k]
         tokens = np.concatenate([last, drafts], axis=1)        # [W, k+1]
         with self._dispatch_span("paged_verify", "verify",
@@ -891,42 +970,45 @@ class PagedBatcher:
                                  k=k, lanes=len(active)):
             logits, self.kv.pool = self._verify(
                 self.params, jnp.asarray(tokens), self.kv.pool,
-                block_table=jnp.asarray(tables),
-                start_index=jnp.asarray(starts))
+                block_table=tables, start_index=starts)
+            with self.tracer.span("verify_sync", track="verify",
+                                  cat="sync"):
+                emitted, n_emit = self._accept(jnp.asarray(drafts), logits)
+                emitted, n_emit = np.asarray(emitted), np.asarray(n_emit)
         self.verify_dispatches += 1
         self.decode_dispatches += 1      # the round's one TARGET dispatch
         self.tracer.count("verify_dispatches")
         self.tracer.count("decode_dispatches")
-        emitted, n_emit = self._accept(jnp.asarray(drafts), logits)
-        emitted, n_emit = np.asarray(emitted), np.asarray(n_emit)
-        for i in active:
-            st = self.lanes[i]
-            e = min(int(n_emit[i]), st.budget)
-            toks = [int(t) for t in emitted[i, :e]]
-            hit_eos = self.eos_id is not None and self.eos_id in toks
-            if hit_eos:
-                toks = toks[: toks.index(self.eos_id) + 1]
-            self.spec_rounds += 1
-            self.tracer.count("spec_rounds")
-            # acceptance rate counts only drafts whose verification row was
-            # budget-covered (rows past the coverage score null-block
-            # garbage) and only acceptances that actually emitted — neither
-            # side of the ratio may include schedule-truncated drafts
-            self.drafted_tokens += min(k, st.budget)
-            self.accepted_tokens += min(int(n_emit[i]) - 1, len(toks))
-            self.tracer.count("drafted_tokens", min(k, st.budget))
-            self.tracer.count("accepted_tokens",
-                              min(int(n_emit[i]) - 1, len(toks)))
-            st.req.output.extend(toks)
-            st.budget -= len(toks)
-            self.decode_steps += len(toks)
-            self.tracer.count("decode_steps", len(toks))
-            new_len = st.seq.length + len(toks)
-            self.kv.truncate_to(st.seq, new_len)    # paged rollback
-            st.seq.length = new_len
-            self.drafts.rollback(i, new_len)        # draft-cache rollback
-            if st.budget <= 0 or hit_eos:
-                self._finish(i)
+        with self.tracer.span("reconcile", track="verify"):
+            for i in active:
+                st = self.lanes[i]
+                e = min(int(n_emit[i]), st.budget)
+                toks = [int(t) for t in emitted[i, :e]]
+                hit_eos = self.eos_id is not None and self.eos_id in toks
+                if hit_eos:
+                    toks = toks[: toks.index(self.eos_id) + 1]
+                self.spec_rounds += 1
+                self.tracer.count("spec_rounds")
+                # acceptance rate counts only drafts whose verification row
+                # was budget-covered (rows past the coverage score
+                # null-block garbage) and only acceptances that actually
+                # emitted — neither side of the ratio may include
+                # schedule-truncated drafts
+                accepted = min(int(n_emit[i]) - 1, len(toks))
+                self.drafted_tokens += min(k, st.budget)
+                self.accepted_tokens += accepted
+                self.tracer.count("drafted_tokens", min(k, st.budget))
+                self.tracer.count("accepted_tokens", accepted)
+                st.req.output.extend(toks)
+                st.budget -= len(toks)
+                self.decode_steps += len(toks)
+                self.tracer.count("decode_steps", len(toks))
+                new_len = st.seq.length + len(toks)
+                self.kv.truncate_to(st.seq, new_len)    # paged rollback
+                st.seq.length = new_len
+                self.drafts.rollback(i, new_len)        # draft rollback
+                if st.budget <= 0 or hit_eos:
+                    self._finish(i)
 
     def _decode_tick(self, active, adm_chunk=None):
         """Host-synced baseline arm: ONE decode step, one dispatch + host
@@ -934,23 +1016,29 @@ class PagedBatcher:
         With ``adm_chunk`` the dispatch is the fused ``mixed_step`` —
         decode step ⊕ prefill chunk — and the chunk's last-token logits
         are returned."""
-        tables = np.zeros((self.W, self.kv.max_blocks_per_seq), np.int32)
-        lengths = np.zeros((self.W,), np.int32)
-        last = np.zeros((self.W, 1), np.int32)
-        for i in active:
-            st = self.lanes[i]
-            self.kv.maybe_grow(st.seq)   # next write may cross a boundary
-            tables[i] = st.seq.table
-            lengths[i] = st.seq.length
-            last[i, 0] = st.req.output[-1]
+        with self.tracer.span("step_prep", track="decode"):
+            tables = np.zeros((self.W, self.kv.max_blocks_per_seq), np.int32)
+            lengths = np.zeros((self.W,), np.int32)
+            last = np.zeros((self.W, 1), np.int32)
+            for i in active:
+                st = self.lanes[i]
+                self.kv.maybe_grow(st.seq)   # next write may cross a block
+                tables[i] = st.seq.table
+                lengths[i] = st.seq.length
+                last[i, 0] = st.req.output[-1]
+            last, tables, lengths = (
+                jnp.asarray(a) for a in (last, tables, lengths))
+            self.rng, k = jax.random.split(self.rng)
         pre_logits = None
         if adm_chunk is None:
             with self._dispatch_span("decode_step", "decode",
                                      ({"m": self.W},), active=len(active)):
                 logits, self.kv.pool = self._decode(
-                    self.params, jnp.asarray(last), self.kv.pool,
-                    block_tables=jnp.asarray(tables),
-                    lengths=jnp.asarray(lengths))
+                    self.params, last, self.kv.pool,
+                    block_tables=tables, lengths=lengths)
+                with self.tracer.span("step_sync", track="decode", cat="sync"):
+                    toks = np.asarray(sample(logits[:, -1, :], k,
+                                             self.sampler))
         else:
             piece, bt, start = adm_chunk
             c = int(piece.shape[1])
@@ -958,28 +1046,29 @@ class PagedBatcher:
                                      ({"mixed": (c, self.W)},),
                                      active=len(active), chunk=c):
                 logits, pre_logits, self.kv.pool = self._mixed(
-                    self.params, jnp.asarray(last), piece, self.kv.pool,
-                    decode_tables=jnp.asarray(tables),
-                    decode_lengths=jnp.asarray(lengths),
+                    self.params, last, piece, self.kv.pool,
+                    decode_tables=tables, decode_lengths=lengths,
                     prefill_table=bt,
                     prefill_start=jnp.asarray(start, jnp.int32))
+                with self.tracer.span("step_sync", track="decode", cat="sync"):
+                    toks = np.asarray(sample(logits[:, -1, :], k,
+                                             self.sampler))
             self.fused_steps += 1
             self.tracer.count("fused_steps")
         self.decode_dispatches += 1
         self.tracer.count("decode_dispatches")
-        self.rng, k = jax.random.split(self.rng)
-        toks = np.asarray(sample(logits[:, -1, :], k, self.sampler))
-        for i in active:
-            st = self.lanes[i]
-            tok = int(toks[i])
-            st.req.output.append(tok)
-            st.seq.length += 1
-            st.budget -= 1
-            self.decode_steps += 1
-            self.tracer.count("decode_steps")
-            if st.budget <= 0 or (self.eos_id is not None
-                                  and tok == self.eos_id):
-                self._finish(i)
+        with self.tracer.span("reconcile", track="decode"):
+            for i in active:
+                st = self.lanes[i]
+                tok = int(toks[i])
+                st.req.output.append(tok)
+                st.seq.length += 1
+                st.budget -= 1
+                self.decode_steps += 1
+                self.tracer.count("decode_steps")
+                if st.budget <= 0 or (self.eos_id is not None
+                                      and tok == self.eos_id):
+                    self._finish(i)
         return pre_logits
 
     def _decode_window(self, active, adm_chunk=None):
@@ -995,24 +1084,25 @@ class PagedBatcher:
         from repro.core.sync import paged_decode_window
 
         w = self.window
-        # core never imports serving: hand the window a live tracer only —
-        # the disabled path passes None and core skips span construction
-        win_tracer = self.tracer if self.tracer.enabled else None
-        tables = np.zeros((self.W, self.kv.max_blocks_per_seq), np.int32)
-        lengths = np.zeros((self.W,), np.int32)
-        remaining = np.zeros((self.W,), np.int32)
-        last = np.zeros((self.W, 1), np.int32)
-        for i in active:
-            st = self.lanes[i]
-            steps = min(w, st.budget)
-            # window writes positions length .. length+steps-1, all inside
-            # the admission reservation (length+steps <= prompt+max_new)
-            self.kv.grow_to(st.seq, st.seq.length + steps)
-            tables[i] = st.seq.table
-            lengths[i] = st.seq.length
-            remaining[i] = steps
-            last[i, 0] = st.req.output[-1]
-        self.rng, sub = jax.random.split(self.rng)
+        with self.tracer.span("window_prep", track="decode"):
+            tables = np.zeros((self.W, self.kv.max_blocks_per_seq), np.int32)
+            lengths = np.zeros((self.W,), np.int32)
+            remaining = np.zeros((self.W,), np.int32)
+            last = np.zeros((self.W, 1), np.int32)
+            for i in active:
+                st = self.lanes[i]
+                steps = min(w, st.budget)
+                # window writes positions length .. length+steps-1, all
+                # inside the admission reservation (length+steps <=
+                # prompt+max_new)
+                self.kv.grow_to(st.seq, st.seq.length + steps)
+                tables[i] = st.seq.table
+                lengths[i] = st.seq.length
+                remaining[i] = steps
+                last[i, 0] = st.req.output[-1]
+            last, tables, lengths, remaining = (
+                jnp.asarray(a) for a in (last, tables, lengths, remaining))
+            self.rng, sub = jax.random.split(self.rng)
         pre_logits = None
         if adm_chunk is None:
             # the compiled window always runs w full-width steps (finished
@@ -1021,12 +1111,13 @@ class PagedBatcher:
                                      ({"m": self.W, "steps": w},),
                                      window=w, active=len(active)):
                 toks, valid, self.kv.pool, _, _ = paged_decode_window(
-                    self.model, self.params, jnp.asarray(last), self.kv.pool,
-                    jnp.asarray(tables), jnp.asarray(lengths),
-                    jnp.asarray(remaining), sub, w,
+                    self.model, self.params, last, self.kv.pool, tables,
+                    lengths, remaining, sub, w,
                     sampler=self.sampler, eos_id=self.eos_id,
-                    decode_step_fn=self._decode_step_fn,
-                    tracer=win_tracer)
+                    decode_step_fn=self._decode_step_fn)
+                with self.tracer.span("window_sync", track="decode",
+                                      cat="sync"):
+                    toks, valid = np.asarray(toks), np.asarray(valid)
         else:
             piece, bt, start = adm_chunk
             c = int(piece.shape[1])
@@ -1039,34 +1130,33 @@ class PagedBatcher:
                                      window=w, active=len(active), chunk=c):
                 toks, valid, pre_logits, self.kv.pool, _, _ = \
                     paged_decode_window(
-                        self.model, self.params, jnp.asarray(last),
-                        self.kv.pool,
-                        jnp.asarray(tables), jnp.asarray(lengths),
-                        jnp.asarray(remaining), sub, w,
+                        self.model, self.params, last, self.kv.pool, tables,
+                        lengths, remaining, sub, w,
                         sampler=self.sampler, eos_id=self.eos_id,
                         prefill_tokens=piece, prefill_table=bt,
                         prefill_start=start,
                         mixed_step_fn=self._mixed_step_fn,
-                        decode_step_fn=self._decode_step_fn,
-                        tracer=win_tracer)
+                        decode_step_fn=self._decode_step_fn)
+                with self.tracer.span("window_sync", track="decode",
+                                      cat="sync"):
+                    toks, valid = np.asarray(toks), np.asarray(valid)
             self.fused_steps += 1
             self.tracer.count("fused_steps")
         self.decode_dispatches += 1
         self.tracer.count("decode_dispatches")
-        toks = np.asarray(toks)
-        valid = np.asarray(valid)
-        for i in active:
-            st = self.lanes[i]
-            emitted = [int(t) for t in toks[i][valid[i]]]
-            st.req.output.extend(emitted)
-            st.seq.length += len(emitted)
-            st.budget -= len(emitted)
-            self.decode_steps += len(emitted)
-            self.tracer.count("decode_steps", len(emitted))
-            hit_eos = (self.eos_id is not None
-                       and self.eos_id in emitted)
-            if st.budget <= 0 or hit_eos:
-                self._finish(i)
+        with self.tracer.span("reconcile", track="decode"):
+            for i in active:
+                st = self.lanes[i]
+                emitted = [int(t) for t in toks[i][valid[i]]]
+                st.req.output.extend(emitted)
+                st.seq.length += len(emitted)
+                st.budget -= len(emitted)
+                self.decode_steps += len(emitted)
+                self.tracer.count("decode_steps", len(emitted))
+                hit_eos = (self.eos_id is not None
+                           and self.eos_id in emitted)
+                if st.budget <= 0 or hit_eos:
+                    self._finish(i)
         return pre_logits
 
     def run(self, requests: list[Request], max_ticks: int = 10_000):

@@ -187,8 +187,10 @@ class DraftLanes:
                     self.tracer.count("draft_dispatches")
                 self.cache = cache
                 toks = jnp.stack(outs, axis=1)
+            with self.tracer.span("draft_sync", track="draft", cat="sync"):
+                toks = np.asarray(toks[:, :k])
         self.idx = self.idx + np.int32(k + 1)
-        return np.asarray(toks[:, :k])
+        return toks
 
     def rollback(self, lane: int, n_tokens: int) -> None:
         """Reset ``lane``'s cursor to the accepted token count — the whole

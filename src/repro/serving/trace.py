@@ -40,11 +40,17 @@ by a deterministic virtual duration inside each dispatch span, so traced
 virtual-time runs get nonzero, reproducible span durations (and therefore
 nonzero drift residuals) without a single real timer.
 
+One clock with the device trace: every span, dispatch and instant also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so when the JAX
+profiler runs, the server's own spans land in its trace beside the device
+programs they caused. With the profiler off an annotation is a no-op.
+
 Zero-overhead-when-off contract: schedulers default to the shared
 :data:`NULL_TRACER` singleton, whose every method is a no-op returning a
-reusable null context — no event is ever recorded, no prediction is ever
-looked up (call sites guard tag computation on ``tracer.enabled``), and
-behavior is bit-identical to the uninstrumented stack.
+reusable null context — no event is ever recorded, no annotation opened,
+no prediction is ever looked up (call sites guard tag computation on
+``tracer.enabled``), and behavior is bit-identical to the uninstrumented
+stack.
 """
 from __future__ import annotations
 
@@ -52,6 +58,8 @@ import json
 from collections import deque
 from contextlib import contextmanager
 from pathlib import Path
+
+import jax
 
 from .telemetry import Clock, MonotonicClock
 
@@ -69,6 +77,7 @@ _PID = 1        # one serving process per trace
 # agree exactly — the contract the fuzz cross-check arm pins on every arm.
 STATS_COUNTER_KEYS = (
     "decode_dispatches", "decode_steps", "prefill_dispatches", "fused_steps",
+    "first_tokens", "prefill_wait_s", "prefill_host_s", "first_token_hold_s",
     "preemptions", "spec_rounds", "drafted_tokens", "accepted_tokens",
     "verify_dispatches", "draft_dispatches",
     "prefix_hits", "prefix_tokens_reused", "evictions", "cow_copies",
@@ -381,7 +390,8 @@ class Tracer:
         self._emit({"name": name, "ph": "B", "ts": self._ts(), "pid": _PID,
                     "tid": tid, "cat": cat, "args": args or {}})
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self._emit({"name": name, "ph": "E", "ts": self._ts(),
                         "pid": _PID, "tid": tid, "cat": cat, "args": {}})
@@ -393,7 +403,12 @@ class Tracer:
         planned it. On exit the measured duration lands in the
         ``dispatch_us`` histogram (labeled by kind) and is attributed
         across ``tags`` — ``(site, M, strategy, t_us, count)`` tuples —
-        into the drift aggregator, proportionally to predicted share."""
+        into the drift aggregator, proportionally to predicted share.
+
+        JAX dispatches asynchronously, so the span measures whatever the
+        caller does inside it: call sites that await the result at once
+        put their ``*_sync`` span inside the dispatch, which then covers
+        enqueue through result; ``prefill_chunk`` times the enqueue only."""
         tid = self._tid(track)
         a = dict(args or {})
         if tags:
@@ -405,7 +420,8 @@ class Tracer:
         self._emit({"name": kind, "ph": "B", "ts": t0, "pid": _PID,
                     "tid": tid, "cat": "dispatch", "args": a})
         try:
-            yield
+            with jax.profiler.TraceAnnotation(kind):
+                yield
         finally:
             if self.cost_model is not None \
                     and hasattr(self.clock, "advance"):
@@ -427,6 +443,8 @@ class Tracer:
     def instant(self, name: str, *, track: str = "scheduler",
                 cat: str = "event", args: dict | None = None,
                 at=None) -> None:
+        with jax.profiler.TraceAnnotation(name):
+            pass
         self._emit({"name": name, "ph": "i", "ts": self._ts(at),
                     "pid": _PID, "tid": self._tid(track), "cat": cat,
                     "s": "t", "args": args or {}})

@@ -385,6 +385,42 @@ def test_schema_contract_catches_docs_drift(tmp_path):
     assert "hits" in msgs and "stale_name" in msgs
 
 
+def test_schema_contract_checks_span_tables(tmp_path):
+    """Sync spans (``cat="sync"``) and host spans are held to their own
+    docs tables, in both directions."""
+    sched = _SCHEMA_SCHED.replace(
+        'self.tracer.gauge("depth", 1)',
+        'self.tracer.gauge("depth", 1)\n'
+        '            with self.tracer.span("wait", cat="sync"):\n'
+        '                pass\n'
+        '            with self.tracer.span("prep"):\n'
+        '                pass')
+    docs = _SCHEMA_DOCS + """
+    **Sync spans**
+
+    | name | meaning |
+    |---|---|
+    | `wait` | the host waits |
+
+    **Host spans**
+
+    | name | meaning |
+    |---|---|
+    | `prep` | host work |
+"""
+    files = {"src/pkg/trace.py": _SCHEMA_TRACE, "src/pkg/sched.py": sched}
+    assert _schema_findings(tmp_path / "ok",
+                            {**files, "docs/obs.md": docs}) == []
+    found = _schema_findings(tmp_path / "drift", {
+        **files, "docs/obs.md": docs.replace("`wait`", "`stale`")})
+    msgs = "\n".join(f.message for f in found)
+    assert "sync span 'wait' is emitted but missing" in msgs
+    assert "names 'stale' but no code emits it" in msgs
+    found = _schema_findings(tmp_path / "host", {
+        **files, "docs/obs.md": docs.replace("| `prep` | host work |\n", "")})
+    assert any("host span 'prep'" in f.message for f in found)
+
+
 def test_schema_contract_collision_between_merged_groups(tmp_path):
     cfg = SchemaConfig(
         trace_relpath="src/pkg/trace.py", docs_relpath="docs/obs.md",

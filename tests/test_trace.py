@@ -288,8 +288,9 @@ def test_stats_schema_collision_free(smoke_model):
     keys, spec keys and ingress keys never collide, and value types are
     stable — the schema the exposition and reconciliation depend on."""
     base = {"tp", "peak_active", "decode_dispatches", "decode_steps",
-            "prefill_dispatches", "fused_steps", "preemptions",
-            "total_dispatches"}
+            "prefill_dispatches", "fused_steps", "first_tokens",
+            "prefill_wait_s", "prefill_host_s", "first_token_hold_s",
+            "preemptions", "total_dispatches"}
     prefix = {"prefix_hits", "prefix_tokens_reused", "evictions",
               "cow_copies", "cached_blocks"}
     spec = {"spec_k", "draft_model", "spec_rounds", "drafted_tokens",
@@ -310,7 +311,9 @@ def test_stats_schema_collision_free(smoke_model):
     for k, v in s.items():
         assert isinstance(v, (int, float, str, np.integer)), (k, type(v))
         if k in STATS_COUNTER_KEYS or k in STATS_GAUGE_KEYS:
-            assert isinstance(v, (int, np.integer)), (k, type(v))
+            # counts are integers; the TTFT stage counters are seconds
+            want = float if k.endswith("_s") else (int, np.integer)
+            assert isinstance(v, want), (k, type(v))
 
 
 def test_counter_reconciliation_exact(smoke_model):
