@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench.host_spans import host_gap
+from bench.host_spans import host_gap, idle_split
 from bench.xtrace import reduce_planes
 
 RECORDED = Path(__file__).with_name("data") / "chat_spans.planes.json.gz"
@@ -44,9 +44,18 @@ def test_only_host_work_outside_syncs_counts():
     assert lost / window <= idle
 
 
+def test_idle_split_places_each_idle_stretch():
+    got = idle_split(_planes(HOST))
+    want = {"idle": 0.075, "in_programs": 0.005, "under_syncs": 0.030,
+            "host_work": 0.010, "outside_ticks": 0.030, "traced": 0.2}
+    assert got == pytest.approx(want)
+    assert got["host_work"] == pytest.approx(host_gap(_planes(HOST))[0])
+
+
 def test_a_program_without_sync_spans_reads_nothing():
     host = [e for e in HOST if e[0] != "window_sync"]
     assert host_gap(_planes(host)) is None
+    assert idle_split(_planes(host)) is None
     assert host_gap([("/host:CPU", {"python": HOST})]) is None
 
 
@@ -68,3 +77,9 @@ def test_recorded_chip_trace():
     assert "jit_paged_prefill" in modules and "jit__unknown" not in modules
     lost, window = host_gap(planes)
     assert 0 < lost / window <= 1 - d["busy_s"] / d["window_s"]
+    split = idle_split(planes)
+    assert split["host_work"] == pytest.approx(lost)
+    assert split["idle"] == pytest.approx(d["window_s"] - d["busy_s"])
+    assert split["idle"] == pytest.approx(sum(
+        split[k] for k in ("in_programs", "under_syncs", "host_work",
+                           "outside_ticks")))

@@ -226,8 +226,9 @@ class ContinuousBatcher:
                                   args={"active": len(active)}):
             logits, self.cache = self._decode(self.params,
                                               jnp.asarray(last), self.cache)
+            toks = sample(logits[:, -1, :], k, self.sampler)
             with self.tracer.span("step_sync", track="decode", cat="sync"):
-                toks = np.asarray(sample(logits[:, -1, :], k, self.sampler))
+                toks = np.asarray(toks)
         self.decode_dispatches += 1
         self.tracer.count("decode_dispatches")
         for b in active:
@@ -689,11 +690,13 @@ class PagedBatcher:
 
     def _first_token(self, logits) -> int:
         """Sample a request's first token from its prefill's last logits:
-        the host waits here for the prefill."""
+        the host dispatches the sampling, then waits here for the prefill
+        (``first_token_sync`` holds the wait alone)."""
         self.rng, k = jax.random.split(self.rng)
+        first = sample(logits[:, -1, :], k, self.sampler)[0]
         with self.tracer.span("first_token_sync", track="prefill",
                               cat="sync"):
-            return int(sample(logits[:, -1, :], k, self.sampler)[0])
+            return int(first)
 
     def _place(self, req: Request, seq: SequenceBlocks, first: int) -> int:
         """Prefill done: record the prefill-sampled token and occupy a lane
@@ -971,9 +974,9 @@ class PagedBatcher:
             logits, self.kv.pool = self._verify(
                 self.params, jnp.asarray(tokens), self.kv.pool,
                 block_table=tables, start_index=starts)
+            emitted, n_emit = self._accept(jnp.asarray(drafts), logits)
             with self.tracer.span("verify_sync", track="verify",
                                   cat="sync"):
-                emitted, n_emit = self._accept(jnp.asarray(drafts), logits)
                 emitted, n_emit = np.asarray(emitted), np.asarray(n_emit)
         self.verify_dispatches += 1
         self.decode_dispatches += 1      # the round's one TARGET dispatch
@@ -1036,9 +1039,9 @@ class PagedBatcher:
                 logits, self.kv.pool = self._decode(
                     self.params, last, self.kv.pool,
                     block_tables=tables, lengths=lengths)
+                toks = sample(logits[:, -1, :], k, self.sampler)
                 with self.tracer.span("step_sync", track="decode", cat="sync"):
-                    toks = np.asarray(sample(logits[:, -1, :], k,
-                                             self.sampler))
+                    toks = np.asarray(toks)
         else:
             piece, bt, start = adm_chunk
             c = int(piece.shape[1])
@@ -1050,9 +1053,9 @@ class PagedBatcher:
                     decode_tables=tables, decode_lengths=lengths,
                     prefill_table=bt,
                     prefill_start=jnp.asarray(start, jnp.int32))
+                toks = sample(logits[:, -1, :], k, self.sampler)
                 with self.tracer.span("step_sync", track="decode", cat="sync"):
-                    toks = np.asarray(sample(logits[:, -1, :], k,
-                                             self.sampler))
+                    toks = np.asarray(toks)
             self.fused_steps += 1
             self.tracer.count("fused_steps")
         self.decode_dispatches += 1

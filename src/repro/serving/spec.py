@@ -187,8 +187,9 @@ class DraftLanes:
                     self.tracer.count("draft_dispatches")
                 self.cache = cache
                 toks = jnp.stack(outs, axis=1)
+            toks = toks[:, :k]
             with self.tracer.span("draft_sync", track="draft", cat="sync"):
-                toks = np.asarray(toks[:, :k])
+                toks = np.asarray(toks)
         self.idx = self.idx + np.int32(k + 1)
         return toks
 

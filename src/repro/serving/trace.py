@@ -40,10 +40,10 @@ by a deterministic virtual duration inside each dispatch span, so traced
 virtual-time runs get nonzero, reproducible span durations (and therefore
 nonzero drift residuals) without a single real timer.
 
-One clock with the device trace: every span, dispatch and instant also
-opens a ``jax.profiler.TraceAnnotation`` of the same name, so when the JAX
-profiler runs, the server's own spans land in its trace beside the device
-programs they caused. With the profiler off an annotation is a no-op.
+One clock with the device trace: every span and dispatch also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so when the JAX profiler
+runs, the server's own spans land in its trace beside the device programs
+they caused. With the profiler off an annotation is a no-op.
 
 Zero-overhead-when-off contract: schedulers default to the shared
 :data:`NULL_TRACER` singleton, whose every method is a no-op returning a
@@ -443,8 +443,6 @@ class Tracer:
     def instant(self, name: str, *, track: str = "scheduler",
                 cat: str = "event", args: dict | None = None,
                 at=None) -> None:
-        with jax.profiler.TraceAnnotation(name):
-            pass
         self._emit({"name": name, "ph": "i", "ts": self._ts(at),
                     "pid": _PID, "tid": self._tid(track), "cat": cat,
                     "s": "t", "args": args or {}})

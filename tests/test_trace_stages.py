@@ -97,13 +97,15 @@ def test_tracer_hooks_open_profiler_annotations(monkeypatch):
     tr = Tracer(FakeClock())
     with tr.span("tick"):
         with tr.dispatch("decode_window"):
-            tr.instant("window_sync")
+            with tr.span("window_sync", cat="sync"):
+                tr.instant("lane_preempt")
     assert rec.opened == [("tick", None), ("decode_window", "tick"),
                           ("window_sync", "decode_window")]
     rec.opened.clear()
     with NULL_TRACER.span("tick"):
         with NULL_TRACER.dispatch("decode_window"):
-            NULL_TRACER.instant("window_sync")
+            with NULL_TRACER.span("window_sync", cat="sync"):
+                NULL_TRACER.instant("lane_preempt")
     assert rec.opened == []
 
 
