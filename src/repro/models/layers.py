@@ -293,8 +293,9 @@ def dequant_kv_ref(codes: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 def paged_attention(
     p: dict, x: jax.Array, cfg, *,
     positions: jax.Array,           # [S] or [B, S] absolute token positions
-    pool: dict,                     # per-layer pool: {"k","v": [NB,BS,Hkv,D]}
-    #                                 (+ "k_scale","v_scale": [NB,BS] if int8)
+    pool: dict,                     # whole pool: {"k","v": [L,NB,BS,Hkv,D]}
+    #                                 (+ "k_scale","v_scale": [L,NB,BS] if int8)
+    layer,                          # this layer's index on the pool's L axis
     block_table: jax.Array,         # [B, NBmax] int32 pool block ids (0=null)
     unroll: bool = False,
     hetero_ctx=None,
@@ -302,13 +303,15 @@ def paged_attention(
 ):
     """GQA attention over a paged KV pool (serving/paged_cache.py).
 
-    Logical position ``t`` of request ``b`` lives at physical slot
-    ``block_table[b, t // BS] * BS + t % BS`` of the flat pool. New K/V are
-    scattered there (``.at[idx].set`` — jittable); reads gather the
-    request's pages with ``jnp.take`` into a ``[B, NBmax*BS]`` view whose
-    slot index IS the logical position, so the standard positional causal
-    mask handles stale pool contents and null-block padding exactly like
-    the dense path masks unwritten cache slots.
+    Logical position ``t`` of request ``b`` lives at slot
+    ``(layer, block_table[b, t // BS], t % BS)`` of the whole pool. New K/V
+    are scattered there, in place (``.at[...].set`` on the pool the layer
+    scan carries — no layer slice is cut out or stacked back); reads gather
+    the request's pages at ``layer`` straight from the whole pool into a
+    ``[B, NBmax*BS]`` view whose slot index IS the logical position, so the
+    standard positional causal mask handles stale pool contents and
+    null-block padding exactly like the dense path masks unwritten cache
+    slots.
 
     An int8 pool (``k_scale``/``v_scale`` present) quantizes on scatter —
     per-slot codes + one scale scalar per (slot, tensor) — and dequantizes
@@ -321,50 +324,39 @@ def paged_attention(
     only collectives are the head gather before ``wo`` and the output-column
     gather after it (both [B, S, d]-sized, bit-exact concatenations).
 
-    Returns (out, updated per-layer pool dict with the same keys).
+    Returns (out, updated whole pool dict with the same keys).
     """
     B, S, _ = x.shape
     hd = cfg.head_dim
-    NB, BS, Hkv, D = pool["k"].shape
+    BS, Hkv, D = pool["k"].shape[2:]
     quant = "k_scale" in pool
     q, k, v, mm = _qkv_rope(p, x, cfg, positions, hetero_ctx)
 
     pos = (positions if positions.ndim == 2
            else jnp.broadcast_to(positions[None, :], (B, S))).astype(jnp.int32)
     blk = jnp.take_along_axis(block_table, pos // BS, axis=1)     # [B, S]
-    flat_idx = (blk * BS + pos % BS).reshape(-1)                  # [B*S]
-    fk = pool["k"].reshape(NB * BS, Hkv, D)
-    fv = pool["v"].reshape(NB * BS, Hkv, D)
+    at = (layer, blk.reshape(-1), (pos % BS).reshape(-1))         # [B*S] slots
+    k = k.reshape(B * S, Hkv, D)
+    v = v.reshape(B * S, Hkv, D)
     new_pool = {}
     if quant:
-        k_codes, k_sc = quantize_kv_slot(k.reshape(B * S, Hkv, D),
-                                         pool["k_scale"].dtype, tp_axis)
-        v_codes, v_sc = quantize_kv_slot(v.reshape(B * S, Hkv, D),
-                                         pool["v_scale"].dtype, tp_axis)
-        fk = fk.at[flat_idx].set(k_codes)
-        fv = fv.at[flat_idx].set(v_codes)
-        new_pool["k_scale"] = pool["k_scale"].reshape(
-            NB * BS).at[flat_idx].set(k_sc).reshape(NB, BS)
-        new_pool["v_scale"] = pool["v_scale"].reshape(
-            NB * BS).at[flat_idx].set(v_sc).reshape(NB, BS)
-    else:
-        fk = fk.at[flat_idx].set(k.reshape(B * S, Hkv, D).astype(fk.dtype))
-        fv = fv.at[flat_idx].set(v.reshape(B * S, Hkv, D).astype(fv.dtype))
-    new_pool["k"] = fk.reshape(NB, BS, Hkv, D)
-    new_pool["v"] = fv.reshape(NB, BS, Hkv, D)
+        k, k_sc = quantize_kv_slot(k, pool["k_scale"].dtype, tp_axis)
+        v, v_sc = quantize_kv_slot(v, pool["v_scale"].dtype, tp_axis)
+        new_pool["k_scale"] = pool["k_scale"].at[at].set(k_sc)
+        new_pool["v_scale"] = pool["v_scale"].at[at].set(v_sc)
+    new_pool["k"] = pool["k"].at[at].set(k.astype(pool["k"].dtype))
+    new_pool["v"] = pool["v"].at[at].set(v.astype(pool["v"].dtype))
 
+    # the same gather semantics as ``jnp.take`` (out-of-range ids fill)
+    pages = lambda a: a.at[layer, block_table].get(mode="fill")
     NBmax = block_table.shape[1]
-    ck = jnp.take(new_pool["k"], block_table, axis=0).reshape(
-        B, NBmax * BS, Hkv, D)
-    cv = jnp.take(new_pool["v"], block_table, axis=0).reshape(
-        B, NBmax * BS, Hkv, D)
+    ck = pages(new_pool["k"]).reshape(B, NBmax * BS, Hkv, D)
+    cv = pages(new_pool["v"]).reshape(B, NBmax * BS, Hkv, D)
     if quant:
-        ck_s = jnp.take(new_pool["k_scale"], block_table, axis=0).reshape(
-            B, NBmax * BS)
-        cv_s = jnp.take(new_pool["v_scale"], block_table, axis=0).reshape(
-            B, NBmax * BS)
-        ck = dequant_kv_ref(ck, ck_s, q.dtype)
-        cv = dequant_kv_ref(cv, cv_s, q.dtype)
+        ck = dequant_kv_ref(ck, pages(new_pool["k_scale"]).reshape(
+            B, NBmax * BS), q.dtype)
+        cv = dequant_kv_ref(cv, pages(new_pool["v_scale"]).reshape(
+            B, NBmax * BS), q.dtype)
     kv_pos = jnp.arange(NBmax * BS, dtype=jnp.int32)
     o = blockwise_attention(q, ck, cv, q_pos=pos, kv_pos=kv_pos,
                             causal=True, block_k=cfg.attn_block_k,

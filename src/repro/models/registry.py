@@ -23,7 +23,9 @@ serving/spec.py); ``paged_decode_step`` is also the body of the fused-window
 decode scan
 (core/sync.py::paged_decode_window): it must stay a pure pool -> pool
 function of statically-shaped operands so a ``lax.scan`` can carry the pool
-across a whole window with zero host round-trips. ``mixed_step`` is the
+across a whole window with zero host round-trips. Inside each step the
+layer scan carries the whole pool too, and every layer writes its K/V into
+it in place, so no step program holds a second copy of the pool. ``mixed_step`` is the
 stage-parallel variant: one dispatch runs every decode lane AND one prefill
 chunk of an admitting request against the same pool (the scheduler's
 mixed-batch mode), with the same purity/static-shape contract.

@@ -56,8 +56,9 @@ def _layer(lp, x, cfg, *, positions, kv=None, cache_index=None, unroll=False,
     if paged is not None:
         attn_out, new_kv = paged_attention(
             lp["attn"], h, cfg, positions=positions,
-            pool=paged["pool"], block_table=paged["block_table"],
-            unroll=unroll, hetero_ctx=hetero_ctx, tp_axis=tp_axis)
+            pool=paged["pool"], layer=paged["layer"],
+            block_table=paged["block_table"], unroll=unroll,
+            hetero_ctx=hetero_ctx, tp_axis=tp_axis)
     else:
         attn_out, new_kv = attention(lp["attn"], h, cfg, positions=positions,
                                      cache=kv, cache_index=cache_index,
@@ -242,34 +243,39 @@ def init_paged_cache(cfg, *, num_blocks: int, block_size: int,
             "v_scale": jnp.zeros(sshape, jnp.bfloat16)}
 
 
-def _run_layers_paged(params, x, cfg, *, positions, pool, block_table,
-                      unroll=False, hetero_ctx=None, tp_axis=None):
-    """Like ``_run_layers`` but attention reads/writes the paged pool;
-    scans over (layer params, per-layer pages) — the pool is a pytree of
-    ``[L, ...]`` leaves (K/V tensors plus the int8 pool's scale planes), so
-    the scan slices every leaf per layer. Returns the updated pool."""
+def _over_layers(params, cfg, body, carry, unroll):
+    """``carry = body(layer_params, layer_index, *carry)`` for every layer:
+    a ``lax.scan`` over (layer params, layer index), or a Python loop when
+    ``unroll``. Returns the last carry."""
     if unroll:
-        new_pools = []
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[i], params["layers"])
-            pl = jax.tree.map(lambda a: a[i], pool)
-            x, npl, _ = _layer(lp, x, cfg, positions=positions, unroll=True,
-                               hetero_ctx=hetero_ctx, tp_axis=tp_axis,
-                               paged={"pool": pl,
-                                      "block_table": block_table})
-            new_pools.append(npl)
-        return x, jax.tree.map(lambda *ls: jnp.stack(ls), *new_pools)
+            carry = body(lp, i, *carry)
+        return carry
+    carry, _ = jax.lax.scan(
+        lambda c, xs: (body(*xs, *c), None), carry,
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return carry
 
-    def step(carry, xs):
-        lp, pl = xs
-        x2, npl, _ = _layer(lp, carry, cfg, positions=positions,
+
+def _run_layers_paged(params, x, cfg, *, positions, pool, block_table,
+                      unroll=False, hetero_ctx=None, tp_axis=None):
+    """Like ``_run_layers`` but attention reads/writes the paged pool. The
+    pool — a pytree of ``[L, ...]`` leaves (K/V tensors plus the int8
+    pool's scale planes) — is carried whole through the layer scan with the
+    hidden state; the scan runs over (layer params, layer index), and each
+    layer writes its new K/V into the pool in place and gathers its pages
+    from it at its index. Carrying the pool, instead of scanning it as
+    ``xs``/``ys``, lets XLA alias it: no program copies the whole pool.
+    Returns the updated pool."""
+    def body(lp, i, x, pool):
+        x, pool, _ = _layer(lp, x, cfg, positions=positions, unroll=unroll,
                             hetero_ctx=hetero_ctx, tp_axis=tp_axis,
-                            paged={"pool": pl,
+                            paged={"pool": pool, "layer": i,
                                    "block_table": block_table})
-        return x2, npl
+        return x, pool
 
-    x, new_pool = jax.lax.scan(step, x, (params["layers"], pool))
-    return x, new_pool
+    return _over_layers(params, cfg, body, (x, pool), unroll)
 
 
 def paged_prefill(params, tokens, pool, cfg, *, block_table, start_index=0,
@@ -338,10 +344,11 @@ def mixed_step(params, decode_tokens, prefill_tokens, pool, cfg, *,
                unroll=False, hetero_ctx=None, tp_axis=None):
     """Stage-parallel mixed batch: ONE dispatch runs a batched paged decode
     step for every lane AND one prefill chunk of an admitting request,
-    sharing a single paged-pool write (paper §4.1-§4.3 applied at stage
-    level: decode is the memory-bound flexible-path stream, the aligned
-    prefill chunk is the compute-bound MXU-path stream, and running them
-    concurrently is what fills both the compute and bandwidth envelopes).
+    sharing one paged pool, carried through the layer scan and written in
+    place by both streams (paper §4.1-§4.3 applied at stage level: decode
+    is the memory-bound flexible-path stream, the aligned prefill chunk is
+    the compute-bound MXU-path stream, and running them concurrently is
+    what fills both the compute and bandwidth envelopes).
 
     decode_tokens: [W, 1]; prefill_tokens: [1, C]; decode_tables: [W, NBmax];
     decode_lengths: [W]; prefill_table: [1, NBmax]. The two streams touch
@@ -358,36 +365,20 @@ def mixed_step(params, decode_tokens, prefill_tokens, pool, cfg, *,
     dec_pos = decode_lengths[:, None].astype(jnp.int32)
     pre_pos = prefill_start + jnp.arange(C, dtype=jnp.int32)
 
-    def body(lp, xd, xp, pl):
+    def body(lp, i, xd, xp, pool):
         # decode lanes first (flexible path), prefill chunk second
         # (solver-planned path); order is arbitrary — disjoint block tables
-        xd2, npd, _ = _layer(lp, xd, cfg, positions=dec_pos, unroll=unroll,
-                             tp_axis=tp_axis,
-                             paged={"pool": pl,
-                                    "block_table": decode_tables})
-        xp2, npp, _ = _layer(lp, xp, cfg, positions=pre_pos, unroll=unroll,
-                             hetero_ctx=hetero_ctx, tp_axis=tp_axis,
-                             paged={"pool": npd,
-                                    "block_table": prefill_table})
-        return xd2, xp2, npp
+        xd2, pool, _ = _layer(lp, xd, cfg, positions=dec_pos, unroll=unroll,
+                              tp_axis=tp_axis,
+                              paged={"pool": pool, "layer": i,
+                                     "block_table": decode_tables})
+        xp2, pool, _ = _layer(lp, xp, cfg, positions=pre_pos, unroll=unroll,
+                              hetero_ctx=hetero_ctx, tp_axis=tp_axis,
+                              paged={"pool": pool, "layer": i,
+                                     "block_table": prefill_table})
+        return xd2, xp2, pool
 
-    if unroll:
-        new_pools = []
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            pl = jax.tree.map(lambda a: a[i], pool)
-            xd, xp, npl = body(lp, xd, xp, pl)
-            new_pools.append(npl)
-        pool = jax.tree.map(lambda *ls: jnp.stack(ls), *new_pools)
-    else:
-        def step(carry, xs):
-            xd, xp = carry
-            lp, pl = xs
-            xd2, xp2, npl = body(lp, xd, xp, pl)
-            return (xd2, xp2), npl
-
-        (xd, xp), pool = jax.lax.scan(
-            step, (xd, xp), (params["layers"], pool))
+    xd, xp, pool = _over_layers(params, cfg, body, (xd, xp, pool), unroll)
 
     xd = rms_norm(xd, params["final_norm"], cfg.norm_eps)
     dec_logits = _head_logits(params, xd, cfg, None, tp_axis)  # flexible path

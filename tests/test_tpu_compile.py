@@ -8,13 +8,18 @@ is described, not attached. These tests compile, at Qwen3-1.7B widths:
     (``tpu_custom_call``) is in the program;
   * the full-width ``paged_decode_step`` and the solver-planned
     (``hetero-tensor``) ``paged_prefill`` from ``jax.eval_shape`` shapes,
-    asserting the program fits one v5e's 16 GB.
+    asserting the program fits one v5e's 16 GB;
+  * the benchmark's two largest step programs at their deployments (the
+    fused 8-step decode window of Qwen3-1.7B and a 256-token InternLM2
+    prefill chunk), asserting the pool is carried through the layer scan:
+    no whole-pool ``copy`` and a small bound on temporaries.
 
 A compile that passes is not a chip run: it says nothing about results or
 times. The topology is described inside a module fixture, never at import:
 only one process at a time may load libtpu, and every test worker imports
 this file.
 """
+import re
 from functools import partial
 
 import jax
@@ -122,4 +127,55 @@ def test_hetero_tensor_paged_prefill_fits_v5e(one_chip, monkeypatch):
                              block_table=i32((1, MAX_BLOCKS_PER_SEQ)),
                              start_index=i32(())).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def _pool_copies(compiled, pool) -> list:
+    """HLO ``copy`` instructions whose result has the pool leaf's shape."""
+    dims = ",".join(map(str, pool["k"].shape))
+    pat = re.compile(rf"= \w+\[{dims}\]\{{[^}}]*\}} copy(?:-start)?\(")
+    return pat.findall(compiled.as_text())
+
+
+def _deployment(name, num_blocks, sh):
+    cfg = get_config(name)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = jax.eval_shape(partial(model.init_paged_cache,
+                                  num_blocks=num_blocks, block_size=32))
+    place = lambda t: jax.tree.map(lambda a: _sds(a.shape, a.dtype, sh), t)
+    return model, place(params), place(pool)
+
+
+def test_decode_window_carries_pool_v5e(one_chip):
+    """The chat cell's fused window: 16 lanes, an 80-block table, 1,281
+    blocks, 8 steps. A pool scanned as the layer scan's xs/ys was copied
+    whole every step and took 6.25 GB of temporaries."""
+    from repro.core.sync import _paged_window
+    model, params, pool = _deployment("qwen3-1.7b", 1281, one_chip)
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    lanes = i32((16,))
+    compiled = _paged_window.lower(
+        params, i32((16, 1)), pool, i32((16, 80)), lanes, lanes,
+        _sds((8, 2), jnp.uint32, one_chip),
+        decode_step=model.paged_decode_step, n_steps=8, sampler=None,
+        eos_id=None).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5e9, f"{temp / 1e9:.2f} GB of temporaries"
+    assert not _pool_copies(compiled, pool)
+    _fits(compiled)
+
+
+def test_prefill_chunk_carries_pool_v5e(one_chip):
+    """The code cell's 256-token prefill chunk: a 130-block table over
+    1,344 blocks. Scanned as xs/ys, the pool took 4.45 GB of temporaries."""
+    model, params, pool = _deployment("internlm-1.8b", 1344, one_chip)
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    prefill = jax.jit(model.paged_prefill, donate_argnums=(2,))
+    compiled = prefill.lower(params, i32((1, 256)), pool,
+                             block_table=i32((1, 130)),
+                             start_index=i32(())).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.5e9, f"{temp / 1e9:.2f} GB of temporaries"
+    assert not _pool_copies(compiled, pool)
     _fits(compiled)
