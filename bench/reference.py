@@ -1,12 +1,12 @@
-"""The plain reference: a float32 dense GQA transformer in `jax.numpy`.
+"""The plain reference: a float32 transformer in `jax.numpy`.
 
 It imports nothing of the program. It reads the widths from the
 configuration file and makes the weights from the seed with
 `model.make_weights`, the same call that made the served weights, then
-computes in float32 at the highest matmul precision: RMSNorm, optional
-per-head q/k RMSNorm (Qwen3), rotary embeddings on the two halves of each
-head, causal softmax attention with grouped KV heads, a SwiGLU MLP, and a
-tied or untied output head.
+computes in float32 at the highest matmul precision: the embedding, the
+layers of the configuration's family (`bench/families/<family>.py`'s
+`layer`, which builds on the helpers here: `_rms`, `_rope`, `_attention`,
+`_mm`), the final RMSNorm, and a tied or untied output head.
 
 Attention runs in blocks of query rows and the layers in a scan, so a
 sequence of several thousand tokens fits on one chip beside nothing else.
@@ -17,6 +17,7 @@ position.
 """
 from __future__ import annotations
 
+import json
 import math
 from functools import partial
 
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import head_dim, make_weights
+from bench.model import family, make_weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -33,6 +34,12 @@ Q_BLOCK = 512
 def _dot(a, b, spec, precision):
     return jnp.einsum(spec, a, b, precision=precision,
                       preferred_element_type=jnp.float32)
+
+
+def _mm(a, b):
+    """[S, d] @ [d, f] in float32 at the highest precision."""
+    return _dot(a.astype(jnp.float32), b.astype(jnp.float32), "sd,df->sf",
+                HIGHEST)
 
 
 def _rms(x, w, eps):
@@ -73,48 +80,20 @@ def _attention(q, k, v, precision):
     return o.reshape(S, hq * d)
 
 
-@partial(jax.jit, static_argnames=("c_items",))
-def _forward(params, tokens, positions, *, c_items):
-    """Logits [len(positions), V] of ``tokens`` [S] at ``positions``."""
-    c = dict(c_items)
-    hq, hkv, hd = c["num_attention_heads"], c["num_key_value_heads"], c["hd"]
-    eps, theta = c["rms_norm_eps"], c["rope_theta"]
+@partial(jax.jit, static_argnames=("layer", "c_json"))
+def _forward(params, tokens, positions, *, layer, c_json):
+    """Logits [len(positions), V] of ``tokens`` [S] at ``positions``, each
+    layer computed by the family's ``layer``."""
+    c = json.loads(c_json)
     S = tokens.shape[0]
     pos = jnp.arange(S)
-    f32 = lambda t: t.astype(jnp.float32)
-    mm = lambda a, b: _dot(f32(a), f32(b), "sd,df->sf", HIGHEST)
     x = params["embed"][tokens].astype(jnp.float32)
-
-    def layer(x, lp):
-        h = _rms(x, lp["attn_norm"], eps)
-        q = mm(h, lp["attn"]["wq"]).reshape(S, hq, hd)
-        k = mm(h, lp["attn"]["wk"]).reshape(S, hkv, hd)
-        v = mm(h, lp["attn"]["wv"]).reshape(S, hkv, hd)
-        if c["qk_norm"]:
-            q = _rms(q, lp["attn"]["q_norm"], eps)
-            k = _rms(k, lp["attn"]["k_norm"], eps)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        o = _attention(q, k, v, HIGHEST)
-        x = x + mm(o, lp["attn"]["wo"])
-        h = _rms(x, lp["ffn_norm"], eps)
-        f = lp["ffn"]
-        a = jax.nn.silu(mm(h, f["w_gate"])) * mm(h, f["w_up"])
-        return x + mm(a, f["w_down"]), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    h = _rms(x[positions], params["final_norm"], eps)
-    head = params["embed"].T if c["tied"] else params["head"]
-    return mm(h, head)
-
-
-def _c_items(c: dict) -> tuple:
-    return tuple(sorted({
-        "num_attention_heads": c["num_attention_heads"],
-        "num_key_value_heads": c["num_key_value_heads"], "hd": head_dim(c),
-        "rms_norm_eps": float(c["rms_norm_eps"]),
-        "rope_theta": float(c["rope_theta"]),
-        "qk_norm": bool(c.get("qk_norm", False)),
-        "tied": bool(c["tie_word_embeddings"])}.items()))
+    x, _ = jax.lax.scan(lambda x, lp: (layer(c, lp, x, pos), None), x,
+                        params["layers"])
+    h = _rms(x[positions], params["final_norm"], float(c["rms_norm_eps"]))
+    head = (params["embed"].T if c["tie_word_embeddings"]
+            else params["head"])
+    return _mm(h, head)
 
 
 class Reference:
@@ -142,7 +121,8 @@ class Reference:
         pos = np.zeros(self.n_pos, np.int32)
         pos[:n] = np.arange(len(prompt) - 1, S)
         out = _forward(self.params, jnp.asarray(toks), jnp.asarray(pos),
-                       c_items=_c_items(self.c))
+                       layer=family(self.c).layer,
+                       c_json=json.dumps(self.c, sort_keys=True))
         return np.asarray(out[:n], np.float64)
 
 

@@ -2,8 +2,8 @@
 import copy
 
 SMOKE_CONFIG = {
-    "name": "smoke", "hidden_size": 64, "intermediate_size": 160,
-    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "name": "smoke", "family": "dense", "hidden_size": 64,
+    "intermediate_size": 160, "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
     "rms_norm_eps": 1e-6, "rope_theta": 1000000.0,
     "tie_word_embeddings": True, "qk_norm": True, "torch_dtype": "bfloat16",
